@@ -36,6 +36,17 @@ REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent
 BENCH_JSON = REPO_ROOT / "BENCH_planner.json"
 
 
+def _cpu_child_env() -> dict:
+    """Environment for a benchmark child process: ``src/`` on its path and
+    JAX pinned to the CPU, so the child never contends for a chip that this
+    process may already hold."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = (str(REPO_ROOT / "src")
+                         + os.pathsep + env.get("PYTHONPATH", ""))
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def optimality_gaps(n_inst: int = 20, seed: int = 0) -> dict:
     """Mean period gap (heuristic / exact - 1) on instances small enough for
     the exact bitmask solver (n<=14, p<=9)."""
@@ -219,13 +230,10 @@ def fused_large_grid(quick: bool = False) -> list:
 
 def fused_bucketed_cold_start(quick: bool = False) -> list:
     """The span-bucketed fused engine's cold-start story, measured in FRESH
-    subprocesses: cold without the persistent compilation cache, cold with a
-    warmed cache (compile replaced by cache load), and the in-process warm
-    steady state.  The with/without-cache delta is the satellite claim of
-    this PR's cold-start work (``enable_persistent_cache`` + donated SoA
-    buffers)."""
-    import tempfile
-
+    subprocesses: cold with the persistent compilation cache turned off,
+    cold with a warmed cache (compile replaced by cache load), and the
+    in-process warm steady state.  The children are pinned to the CPU: they
+    measure host cold starts and must never race this process for a chip."""
     from repro.core import fused
 
     n, p, pairs, nb = (9, 7, 3, 4) if quick else (20, 100, 8, 6)
@@ -234,9 +242,11 @@ def fused_bucketed_cold_start(quick: bool = False) -> list:
     child = (
         "import time, sys\n"
         "from repro.core import fused\n"
-        "cache = sys.argv[1]\n"
-        "if cache != 'none':\n"
-        "    fused.enable_persistent_cache(cache)\n"
+        "import jax\n"
+        "if sys.argv[1] == 'cache':\n"
+        "    fused.enable_persistent_cache()\n"
+        "else:\n"
+        "    jax.config.update('jax_enable_compilation_cache', False)\n"
         "from repro.sim.experiments import run_campaign\n"
         "t0 = time.perf_counter()\n"
         f"run_campaign({exps!r}, {n}, {p}, n_pairs={pairs}, n_bounds={nb},\n"
@@ -245,10 +255,7 @@ def fused_bucketed_cold_start(quick: bool = False) -> list:
     )
 
     def run_child(cache_arg):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = (str(REPO_ROOT / "src")
-                             + os.pathsep + env.get("PYTHONPATH", ""))
-        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        env = _cpu_child_env()
         out = subprocess.run([sys.executable, "-c", child, cache_arg],
                              capture_output=True, text=True, env=env,
                              check=True)
@@ -258,9 +265,8 @@ def fused_bucketed_cold_start(quick: bool = False) -> list:
         raise RuntimeError(f"no timing in child output: {out.stdout!r}")
 
     us_nocache = run_child("none")
-    with tempfile.TemporaryDirectory(prefix="repro-jax-cache-") as cachedir:
-        run_child(cachedir)                  # populate the cache
-        us_cached = run_child(cachedir)      # fresh process, warm cache
+    run_child("cache")                   # populate the cache
+    us_cached = run_child("cache")       # fresh process, warm cache
 
     # in-process warm steady state of the same campaign shape
     kw = dict(n_pairs=pairs, n_bounds=nb, h4_iters=4, include_h4=True)
@@ -450,11 +456,9 @@ def sharded_campaign(quick: bool = False) -> list:
         "print('SHARDED_US=%.0f' % (ts * 1e6))\n"
         "print('IDENTICAL=%d' % ident)\n"
     )
-    env = dict(os.environ)
-    env["PYTHONPATH"] = (str(REPO_ROOT / "src")
-                         + os.pathsep + env.get("PYTHONPATH", ""))
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env = _cpu_child_env()
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=8").strip()
     out = subprocess.run([sys.executable, "-c", child], capture_output=True,
                          text=True, env=env, check=True)
     vals = dict(line.split("=", 1) for line in out.stdout.splitlines()
@@ -575,17 +579,16 @@ def deal_speedup(quick: bool = False) -> list:
 
 
 def run(quick: bool = False) -> list:
-    # point the persistent compilation cache at a FRESH per-run directory:
     # the in-process cold rows below must measure real trace+compile cost
-    # every run (a warm machine-wide cache would silently turn them into
-    # cache loads and corrupt the cross-PR perf trajectory); the cache's
+    # every run (a warm cache would silently turn them into cache loads), so
+    # the cache is placed as usual but turned off in this process; its
     # cross-process win is measured explicitly by fused_bucketed_cold_start
-    import tempfile
+    import jax
 
     from repro.core.fused import enable_persistent_cache
 
-    _cache_tmp = tempfile.TemporaryDirectory(prefix="repro-bench-jax-cache-")
-    enable_persistent_cache(_cache_tmp.name)
+    enable_persistent_cache()
+    jax.config.update("jax_enable_compilation_cache", False)
     rows = timing(reps=2 if quick else 10)
     rows += vectorized_eval(reps=2 if quick else 5)
     rows += campaign_speedup(quick=quick)

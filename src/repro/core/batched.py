@@ -619,10 +619,22 @@ def _run_loop(state: _BatchState, k: int, bi_mode: np.ndarray, stop: np.ndarray,
                             np.asarray(stop, dtype=float),
                             np.asarray(lat_limit, dtype=float), record)
         return
+    _numpy_loop(state, np.nonzero(state.active)[0], k, bi_mode, stop,
+                lat_limit, backend, record)
+
+
+def _numpy_loop(state: _BatchState, rows: np.ndarray, k: int,
+                bi_mode: np.ndarray, stop: np.ndarray, lat_limit: np.ndarray,
+                backend: str = "numpy", record: Optional[Callable] = None,
+                max_iters: Optional[int] = None) -> None:
+    """The host lockstep loop of :func:`_run_loop` over the active ``rows``,
+    for at most ``max_iters`` iterations (rows still splitting after them
+    stay active)."""
     pb = state.pb
     be = _get_backend(backend)
-    rows = np.nonzero(state.active)[0]
-    while rows.size:
+    it = 0
+    while rows.size and (max_iters is None or it < max_iters):
+        it += 1
         # 1. natural stop: period bound already satisfied.  Only the first
         # max(m) item columns are live (cycle padding is -inf beyond).
         mm = int(state.m[rows].max())
@@ -929,11 +941,16 @@ def batched_sp_bi_p(batch, bounds, iters: int = 40, backend: str = "numpy",
         groups = np.arange(B)
     groups = np.asarray(groups)
     lo, hi = h4_search_bounds(pb, groups)
-    if backend in ("fused", "sharded") and min(pb.n - 1, pb.p - 1) > 0:
+    from . import fused
+
+    if (backend in ("fused", "sharded") and min(pb.n - 1, pb.p - 1) > 0
+            and not fused.device_band()):
         # the bisection itself is fused (one probe0 + lax.scan program per
         # row-chunk — sharded over the device mesh for backend="sharded");
         # probe-run dedup is pointless when probes are free, so `groups`
-        # is ignored — results are identical either way.
+        # is ignored — results are identical either way.  Where the device's
+        # float64 is not IEEE the probes run the certified loop one by one,
+        # and the bisection's own arithmetic stays on the host.
         return _sp_bi_p_fused(pb, p_fix, iters, lo, hi, with_mappings,
                               backend)
     if not with_mappings:

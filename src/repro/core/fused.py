@@ -49,6 +49,17 @@ FMA contraction of ``a * b + c`` chains (neutralized by the kernels' runtime-
 reductions are order-exact).  The numpy engine remains the contractual
 bit-exact reference; the fused engine is validated against it per test grid.
 
+Devices without IEEE float64: a TPU emulates float64 with float32 pairs
+(about 2^-44 relative per operation on a v5e), so the traced floats cannot
+match numpy there, and exact ties of the integer-valued Section-5 instances
+break differently.  There the loop runs CERTIFIED (:func:`device_band`,
+:func:`_build_loop`): the device decides every step whose outcome is settled
+by more than the error band, parks the rows whose step is not, and the host
+replays the device's decisions and makes the parked ones in float64 with the
+numpy engine's own code (:func:`run_loop`) — same trajectories and floats,
+at the price of one more dispatch round per parked step.  The H4 bisection
+then probes through this loop from the host (``batched.batched_sp_bi_p``).
+
 Cold starts amortize across processes through JAX's persistent compilation
 cache (:func:`enable_persistent_cache` — benchmarks enable it by default).
 
@@ -69,7 +80,8 @@ import numpy as np
 from .heuristics import _EPS, score_2way_kernel, score_3way_kernel
 
 __all__ = ["fused_available", "run_fused", "run_fused_bisection",
-           "trace_count", "reset_trace_count",
+           "trace_count", "reset_trace_count", "traced_shapes",
+           "decision_counts", "device_band", "run_loop",
            "dispatch_count", "reset_dispatch_count",
            "bucket_trace_count", "reset_bucket_trace_count",
            "bucket_sizes", "bucket_index", "trace_budget",
@@ -79,6 +91,8 @@ __all__ = ["fused_available", "run_fused", "run_fused_bisection",
 # reset; incremented from inside the traced wrappers, which Python-execute
 # only while jax is tracing — so this counts actual traces, not dispatches.
 _TRACES = [0]
+# (n, p) shapes of the programs behind those traces
+_SHAPES: set = set()
 # number of traced bucket BRANCHES since the last reset: each program trace
 # traces every bucket of its arity exactly once (lax.switch compiles all
 # branches), so this counter realizes the O(log n)-buckets-per-arity cap.
@@ -87,6 +101,22 @@ _BUCKET_TRACES = [0]
 # reset: one per row-chunk for the lockstep loop, one per row-chunk for the
 # WHOLE H4 bisection (probe-at-hi + the lax.scan over probe iterations).
 _DISPATCHES = [0]
+# decisions of certified loops since the last dispatch-count reset: splits
+# the device decided, and parked row-steps the host re-decided in float64
+_DECISIONS = {"device": 0, "host": 0}
+
+# Relative error bound, against each row's magnitude scale, that certified
+# loops allow the device arithmetic.  TPU float64 is emulated with float32
+# pairs: single operations measured within 2^-44 of IEEE float64 on a v5e
+# (add, mul, div, reciprocal), so a decision chain of a few dozen operations
+# plus the latency sum carried over <= n iterations stays below 2^-35, a
+# factor of 2^7 inside this band.
+TPU_BAND = 2.0 ** -28
+
+# per-iteration decision record: worst-interval index, the (up to 3) parts'
+# first stage, last stage and processor, part count, processors consumed
+DEC_FIELDS = ("widx", "d0", "d1", "d2", "e0", "e1", "e2", "u0", "u1", "u2",
+              "nparts", "consumed")
 
 # lane budget per jitted call: rows_per_chunk * candidate_lanes is held under
 # this so the 3-way pair grid of large n stays cache-/memory-sized.  Sized
@@ -116,6 +146,13 @@ def trace_count() -> int:
 
 def reset_trace_count() -> None:
     _TRACES[0] = 0
+    _SHAPES.clear()
+
+
+def traced_shapes() -> list:
+    """Sorted distinct ``(n, p)`` of the programs traced since the last
+    :func:`reset_trace_count` — each is a separate compile on a device."""
+    return sorted(_SHAPES)
 
 
 def bucket_trace_count() -> int:
@@ -137,6 +174,24 @@ def dispatch_count() -> int:
 
 def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
+    _DECISIONS.update(device=0, host=0)
+
+
+def decision_counts() -> dict:
+    """Certified-loop decisions since :func:`reset_dispatch_count`:
+    ``device`` splits decided on the device, ``host`` parked row-steps
+    re-decided on the host (both 0 where :func:`device_band` is 0)."""
+    return dict(_DECISIONS)
+
+
+def device_band() -> float:
+    """Relative error band of the device's float64 for the fused loop: 0 on
+    backends with IEEE float64 (CPU, GPU), whose decisions are exact as
+    traced; :data:`TPU_BAND` on a TPU, where the loop is certified and
+    parks what it cannot decide (see :func:`_build_loop`)."""
+    import jax
+
+    return TPU_BAND if jax.default_backend() == "tpu" else 0.0
 
 
 @functools.lru_cache(maxsize=None)
@@ -184,15 +239,22 @@ def trace_budget(n: int) -> int:
     return 2 * len(bucket_sizes(n, 1)) + len(bucket_sizes(n, 2))
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> str:
+#: Compile-cache directory used when ``JAX_COMPILATION_CACHE_DIR`` is unset:
+#: a fixed path inside the checkout (the path is part of the cache key, so a
+#: directory that moves between runs never hits).
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_persistent_cache() -> str:
     """Point JAX at an on-disk compilation cache so fused-program cold starts
     are paid once per machine, not once per process.  Idempotent; returns the
-    cache directory.  Benchmarks call this by default (``JAX_COMPILATION_
-    CACHE_DIR`` overrides the location)."""
+    cache directory: ``JAX_COMPILATION_CACHE_DIR`` when set, otherwise
+    :data:`DEFAULT_CACHE_DIR`.  Entry points call this before their first
+    compile."""
     import jax
 
-    path = str(path or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-               or pathlib.Path.home() / ".cache" / "repro-jax-cache")
+    path = str(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+               or DEFAULT_CACHE_DIR)
     pathlib.Path(path).mkdir(parents=True, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", path)
     # persist only compiles that meaningfully cost (the fused programs take
@@ -225,21 +287,36 @@ def _lex_argmin_traced(xp, keys, mask):
     return xp.argmax(m, axis=1), has
 
 
-def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
+def _build_loop(n: int, p: int, k: int, T: int, S: int,
+                band: float = 0.0) -> tuple:
     """Build the UNJITTED fused loop for static shape (n, p, k).
 
     Returns ``(init_state, loop)``:
 
         init_state(delta, s, b, prefix, order) -> (arr, m, nx, lat, sp)
         loop(delta, s, b, zero, prefix, order, bi_mode, stop, lat_limit,
-             active0, arr0, m0, nx0, lat0, sp0)
-          -> (arr, m, next_idx, lat_sum, splits, per_rec, lat_rec, acc_rec, t)
+             active0, arr0, m0, nx0, lat0, sp0, scale, sbits)
+          -> (arr, m, next_idx, lat_sum, splits, parked,
+              per_rec, lat_rec, acc_rec, dec_rec, t)
 
-    with ``arr`` (S, n, 5) in the ``_BatchState`` field layout and the records
-    (T, S) per lockstep iteration.  Callers jit the loop with the SoA state
-    arguments donated (:func:`_get_loop`) or inline it into a larger traced
-    program (:func:`_get_bisect`).  Candidate scoring runs through a
+    with ``arr`` (S, n, 5) in the ``_BatchState`` field layout, the records
+    (T, S) per lockstep iteration and ``dec_rec`` (T, S, 12) the decision of
+    each iteration (:data:`DEC_FIELDS`).  Callers jit the loop with the SoA
+    state arguments donated (:func:`_get_loop`) or inline it into a larger
+    traced program (:func:`_get_bisect`).  Candidate scoring runs through a
     ``lax.switch`` over the geometric span buckets of :func:`bucket_sizes`.
+
+    ``band > 0`` builds the CERTIFIED loop for devices whose float64 is not
+    IEEE (see :func:`device_band`): ``scale`` (S,) bounds each row's
+    magnitudes and ``sbits`` (S, p) holds the speeds' exact bit patterns.
+    Every decision whose outcome could differ under exact float64 — a stop
+    test, worst-interval pick, feasibility mask or key-1 winner within
+    ``band * scale`` (relative error bound of the device arithmetic) of the
+    other outcome — PARKS the row instead (``parked``); the host then makes
+    that one decision in float64 (:func:`run_loop`).  Lanes whose keys are
+    equal because their speeds are bit-equal are exact ties on both sides and
+    resolve by the (exact) position key, so they never park.  ``band == 0``
+    (IEEE float64 devices) leaves every row unparked.
     """
     import jax
 
@@ -255,6 +332,32 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
     def take1(A, idx):
         return jnp.take_along_axis(A, idx[:, None], axis=1)[:, 0]
 
+    def feasible(mx, lat, old_cycle, lat_lim, base, gam, gam_lat):
+        """Lanes with ``mx < old - eps`` and ``lat <= limit + eps`` among
+        ``base``, and per row whether any lane's feasibility lies within the
+        error band (always False when ``band == 0``)."""
+        old = old_cycle[:, None] - _EPS
+        lim = lat_lim[:, None] + _EPS
+        okay = (mx < old) & (lat <= lim) & base
+        if not band:
+            return okay, jnp.zeros(okay.shape[0], dtype=bool)
+        g, gl = gam[:, None], gam_lat[:, None]
+        sure = (mx < old - g) & (lat <= lim - gl) & base
+        maybe = (mx < old + g) & (lat <= lim + gl) & base
+        return sure, (maybe & ~sure).any(axis=1)
+
+    def ratio_band(ratio, den_min, g):
+        """Error bound of ``dlat / max(old - cyc, eps)`` keys: numerator and
+        denominators are each off by at most ``g``."""
+        return g * (1.0 + jnp.abs(ratio)) / jnp.maximum(den_min - g, _EPS)
+
+    def conflict(key1, band1, okay, q, dup):
+        """Rows whose key-1 winner ``q`` is not certified: a feasible lane
+        that is not an exact duplicate of ``q`` (``dup`` holds ``q``) lies
+        within the two lanes' error bands of it."""
+        hi = take1(key1, q) + take1(band1, q)
+        return (okay & ~dup & (key1 - band1 <= hi[:, None])).any(axis=1)
+
     def make_choose_2way(L: int) -> Callable:
         """Scoring/selection branch over the L-cut bucket: interval-relative
         cut lanes ``c = d + offset`` (same compaction as the numpy engine's
@@ -264,7 +367,8 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
         def choose(ops):
             _BUCKET_TRACES[0] += 1  # Python-executes once per branch trace
             (prefix, delta, b, zero, d, e, j, jp_, bi, old_cycle, cur_lat,
-             lat_lim, live, pre_d1, pre_e, del_d1, del_e, inv_j, inv_p) = ops
+             lat_lim, live, pre_d1, pre_e, del_d1, del_e, inv_j, inv_p,
+             gam, gam_lat, same) = ops
             c = d[:, None] + off[None, :]
             valid = c < e[:, None]
             ci = jnp.minimum(c, n - 1)           # in-range gather, masked lanes
@@ -275,10 +379,10 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
                 del_d1[:, None], del_C, del_e[:, None], b,
                 inv_j[:, None], inv_p[:, None], xp=jnp, zero=zero)
             mx = jnp.maximum(cyc1, cyc2)
-            okay = (mx < old_cycle[:, None] - _EPS)
-            okay &= cur_lat[:, None] + dlat <= lat_lim[:, None] + _EPS
-            okay &= jnp.concatenate([valid, valid], axis=1)
-            okay &= live[:, None]
+            okay, unc = feasible(
+                mx, cur_lat[:, None] + dlat, old_cycle, lat_lim,
+                jnp.concatenate([valid, valid], axis=1) & live[:, None],
+                gam, gam_lat)
             ratio = jnp.maximum(
                 dlat / jnp.maximum(old_cycle[:, None] - cyc1, _EPS),
                 dlat / jnp.maximum(old_cycle[:, None] - cyc2, _EPS))
@@ -288,6 +392,17 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
             keys = [jnp.where(bc, ratio, mx), jnp.where(bc, mx, dlat),
                     cutorder]
             q, has = _lex_argmin_traced(jnp, keys, okay)
+            if band:
+                # the other placement order of q's cut ties q exactly when
+                # the two speeds are bit-equal
+                g = gam[:, None]
+                dmin = jnp.minimum(old_cycle[:, None] - cyc1,
+                                   old_cycle[:, None] - cyc2)
+                band1 = jnp.where(bc, ratio_band(ratio, dmin, g), g)
+                lane = jnp.arange(2 * L)[None, :]
+                dup = ((lane % L == (q % L)[:, None])
+                       & ((lane == q[:, None]) | same[:, None]))
+                unc |= has & conflict(keys[0], band1, okay, q, dup)
             cw = d + (q % L)
             swapped = q >= L
             pa = jnp.where(swapped, jp_, j)
@@ -297,7 +412,7 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
             pu = jnp.stack([pa, pb2, pb2], axis=1)
             nparts = jnp.full((S,), 2, dtype=jnp.int64)
             consumed = jnp.ones((S,), dtype=jnp.int64)
-            return has, pd, pe, pu, nparts, consumed
+            return has, pd, pe, pu, nparts, consumed, unc
 
         return choose
 
@@ -317,7 +432,8 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
             _BUCKET_TRACES[0] += 1  # Python-executes once per branch trace
             (prefix, delta, b, zero, d, e, bi, old_cycle, cur_lat, lat_lim,
              live, span2, pre_d1, pre_e, del_d1, del_e, invp, base_term,
-             procs3, mx_fb, dlat_fb, ratio_fb, okay_fb) = ops
+             procs3, mx_fb, dlat_fb, ratio_fb, okay_fb, unc, dmin_fb,
+             gam, gam_lat, cls3) = ops
             bc = bi[:, None]
             if K:
                 c1 = d[:, None] + r1[None, :]
@@ -345,11 +461,12 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
                 mx_f = mx.reshape(S, 6 * K)
                 dlat_f = dlat.reshape(S, 6 * K)
                 ratio_f = ratio.reshape(S, 6 * K)
-                okay3 = mx_f < old_cycle[:, None] - _EPS
-                okay3 &= cur_lat[:, None] + dlat_f <= lat_lim[:, None] + _EPS
-                okay3 &= jnp.broadcast_to(valid[:, None, :],
-                                          (S, 6, K)).reshape(S, 6 * K)
-                okay3 &= (live & ~span2)[:, None]
+                okay3, unc3 = feasible(
+                    mx_f, cur_lat[:, None] + dlat_f, old_cycle, lat_lim,
+                    jnp.broadcast_to(valid[:, None, :], (S, 6, K)
+                                     ).reshape(S, 6 * K)
+                    & (live & ~span2)[:, None], gam, gam_lat)
+                unc |= unc3
                 # (c1, c2, perm) tie-break as ONE exactly-represented integer
                 # key — absolute positions, so bucket layout cannot matter
                 ccp = ((c1 * (n + 1) + c2)[:, None, :] * 6
@@ -395,13 +512,35 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
             pu_f = jnp.stack([pu0, pu1, pu1], axis=1)
             cons_f = jnp.where((ia != 0) & (ib != 0), 2, 1).astype(jnp.int64)
 
+            if band:
+                # lanes whose permuted speeds are bit-equal to q's tie q
+                # exactly: same cut pair (grid) or same part speeds (fallback)
+                g = gam[:, None]
+                band1 = jnp.where(bc, ratio_band(ratio_fb, dmin_fb, g), g)
+                ca, cb = cls3[:, _FB_A], cls3[:, _FB_B]
+                dup = ((ca == take1(ca, qf)[:, None])
+                       & (cb == take1(cb, qf)[:, None]) & fb[:, None])
+                if K:
+                    dmin = (old_cycle[:, None, None, None] - cyc).min(axis=2)
+                    band3 = jnp.where(
+                        bc, ratio_band(ratio_f, dmin.reshape(S, 6 * K), g), g)
+                    cp = cls3[:, _PERMS3]                             # (S,6,3)
+                    cq = jnp.take_along_axis(cp, pi[:, None, None], axis=1)
+                    dup3 = ((cp == cq).all(axis=2)[:, :, None]
+                            & (jnp.arange(K)[None, None, :]
+                               == kk[:, None, None]))
+                    band1 = jnp.concatenate([band3, band1], axis=1)
+                    dup = jnp.concatenate(
+                        [dup3.reshape(S, 6 * K) & ~fb[:, None], dup], axis=1)
+                unc |= has & conflict(key1, band1, okay, q, dup)
+
             fbc = fb[:, None]
             pd = jnp.where(fbc, pd_f, pd_g)
             pe = jnp.where(fbc, pe_f, pe_g)
             pu = jnp.where(fbc, pu_f, u_grid)
             nparts = jnp.where(fb, 2, 3).astype(jnp.int64)
             consumed = jnp.where(fb, cons_f, 2).astype(jnp.int64)
-            return has, pd, pe, pu, nparts, consumed
+            return has, pd, pe, pu, nparts, consumed, unc
 
         return choose
 
@@ -429,11 +568,20 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
         return arr, m0, nx0, term0, sp0
 
     def loop(delta, s, b, zero, prefix, order, bi_mode, stop, lat_limit,
-             active0, arr0, m0, nx0, lat0, sp0):
+             active0, arr0, m0, nx0, lat0, sp0, scale, sbits):
         tail = delta[:, n] / b
         per_rec = jnp.zeros((T, S))
         lat_rec = jnp.zeros((T, S))
         acc_rec = jnp.zeros((T, S), dtype=bool)
+        dec_rec = jnp.zeros((T, S, len(DEC_FIELDS)), dtype=jnp.int64)
+        if band:
+            gam = band * scale
+            gam_lat = band * (scale + jnp.where(jnp.isfinite(lat_limit),
+                                                jnp.abs(lat_limit), 0.0))
+            gam_stop = band * (scale + jnp.where(jnp.isfinite(stop),
+                                                 jnp.abs(stop), 0.0))
+        else:
+            gam = gam_lat = gam_stop = jnp.zeros(S)
 
         def cond(carry):
             t, active = carry[0], carry[5]
@@ -442,11 +590,19 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
         def body(carry):
             (t, arr, m, next_idx, lat_sum, active,
              per_rec, lat_rec, acc_rec) = carry[:9]
-            splits = carry[9]
+            splits, parked, dec_rec = carry[9:]
             cyc = arr[:, :, 3]
             per = cyc.max(axis=1)
             live = active & (per > stop + _EPS)
             widx = jnp.argmax(cyc, axis=1)
+            unc = jnp.zeros(S, dtype=bool)
+            if band:
+                # stop test and worst-interval pick within the error band
+                second = jnp.where(col == widx[:, None], -jnp.inf,
+                                   cyc).max(axis=1)
+                unc = active & ((jnp.abs(per - (stop + _EPS)) <= gam_stop)
+                                | (live & (second >= per - gam)))
+                live &= ~unc
             item = jnp.take_along_axis(arr, widx[:, None, None], axis=1)[:, 0, :]
             d = jnp.clip(item[:, 0].astype(jnp.int64), 1, n)
             e = jnp.clip(item[:, 1].astype(jnp.int64), 1, n)
@@ -468,15 +624,11 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
                 inv_p = 1.0 / take1(s, jp_)
                 need = e - d                      # candidate cuts per row
                 cur = jnp.max(jnp.where(live, need, 0))
+                same = take1(sbits, j) == take1(sbits, jp_)
                 ops = (prefix, delta, b, zero, d, e, j, jp_, bi_mode,
                        old_cycle, cur_lat, lat_limit, live,
-                       pre_d1, pre_e, del_d1, del_e, inv_j, inv_p)
-                if len(branches) > 1:
-                    bidx = jnp.sum(cur > jnp.asarray(thresholds))
-                    (has, pd, pe, pu,
-                     nparts, consumed) = lax.switch(bidx, branches, ops)
-                else:
-                    has, pd, pe, pu, nparts, consumed = branches[0](ops)
+                       pre_d1, pre_e, del_d1, del_e, inv_j, inv_p,
+                       gam, gam_lat, same)
             else:
                 jpp = take1(order, jnp.clip(next_idx + 1, 0, p - 1))
                 sj = take1(s, j)
@@ -501,27 +653,32 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
                 cyc2_fb = t2 + del_e[:, None] / b
                 dlat_fb = (t1 + t2) - base_term[:, None]
                 mx_fb = jnp.maximum(cyc1_fb, cyc2_fb)
-                okay_fb = mx_fb < old_cycle[:, None] - _EPS
-                okay_fb &= (cur_lat[:, None] + dlat_fb
-                            <= lat_limit[:, None] + _EPS)
-                okay_fb &= (live & span2)[:, None]
+                okay_fb, unc_fb = feasible(
+                    mx_fb, cur_lat[:, None] + dlat_fb, old_cycle, lat_limit,
+                    (live & span2)[:, None], gam, gam_lat)
                 ratio_fb = jnp.maximum(
                     dlat_fb / jnp.maximum(old_cycle[:, None] - cyc1_fb, _EPS),
                     dlat_fb / jnp.maximum(old_cycle[:, None] - cyc2_fb, _EPS))
+                dmin_fb = jnp.minimum(old_cycle[:, None] - cyc1_fb,
+                                      old_cycle[:, None] - cyc2_fb)
+                cls3 = jnp.stack([take1(sbits, j), take1(sbits, jp_),
+                                  take1(sbits, jpp)], axis=1)
 
                 span = e - d + 1
                 cur = jnp.max(jnp.where(live & ~span2, span, 0))
                 ops = (prefix, delta, b, zero, d, e, bi_mode, old_cycle,
                        cur_lat, lat_limit, live, span2, pre_d1, pre_e,
                        del_d1, del_e, invp, base_term, procs3,
-                       mx_fb, dlat_fb, ratio_fb, okay_fb)
-                if len(branches) > 1:
-                    bidx = jnp.sum(cur > jnp.asarray(thresholds))
-                    (has, pd, pe, pu,
-                     nparts, consumed) = lax.switch(bidx, branches, ops)
-                else:
-                    has, pd, pe, pu, nparts, consumed = branches[0](ops)
-            accept = live & has
+                       mx_fb, dlat_fb, ratio_fb, okay_fb, unc_fb, dmin_fb,
+                       gam, gam_lat, cls3)
+            if len(branches) > 1:
+                bidx = jnp.sum(cur > jnp.asarray(thresholds))
+                (has, pd, pe, pu, nparts, consumed,
+                 unc_c) = lax.switch(bidx, branches, ops)
+            else:
+                has, pd, pe, pu, nparts, consumed, unc_c = branches[0](ops)
+            unc |= live & unc_c
+            accept = live & has & ~unc
 
             # apply splits (same division-based expressions as _apply_splits)
             pdc = jnp.clip(pd, 1, n)
@@ -563,29 +720,36 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int) -> tuple:
             per_rec = per_rec.at[t].set(arr[:, :, 3].max(axis=1))
             lat_rec = lat_rec.at[t].set(lat_sum + tail)
             acc_rec = acc_rec.at[t].set(accept)
+            dec_rec = dec_rec.at[t].set(jnp.concatenate(
+                [widx[:, None], pdc, pec, puc, nparts[:, None],
+                 consumed[:, None]], axis=1).astype(jnp.int64))
             return (t + 1, arr, m, next_idx, lat_sum, accept,
-                    per_rec, lat_rec, acc_rec, splits)
+                    per_rec, lat_rec, acc_rec, splits, parked | unc, dec_rec)
 
         init = (jnp.int64(0), arr0, m0, nx0, lat0, active0,
-                per_rec, lat_rec, acc_rec, sp0)
-        (t, arr, m, next_idx, lat_sum, active,
-         per_rec, lat_rec, acc_rec, splits) = lax.while_loop(cond, body, init)
-        return arr, m, next_idx, lat_sum, splits, per_rec, lat_rec, acc_rec, t
+                per_rec, lat_rec, acc_rec, sp0, jnp.zeros(S, dtype=bool),
+                dec_rec)
+        (t, arr, m, next_idx, lat_sum, active, per_rec, lat_rec, acc_rec,
+         splits, parked, dec_rec) = lax.while_loop(cond, body, init)
+        return (arr, m, next_idx, lat_sum, splits, parked,
+                per_rec, lat_rec, acc_rec, dec_rec, t)
 
     return init_state, loop
 
 
 @functools.lru_cache(maxsize=None)
-def _get_loop(n: int, p: int, k: int, T: int, S: int) -> Callable:
+def _get_loop(n: int, p: int, k: int, T: int, S: int,
+              band: float = 0.0) -> Callable:
     """The jitted fused loop for static shape (n, p, k), cached per shape.
     The five carried SoA state buffers (arr, m, next_idx, lat_sum, splits)
     are donated: XLA reuses their device buffers for the outputs."""
     import jax
 
-    _init_state, loop = _build_loop(n, p, k, T, S)
+    _init_state, loop = _build_loop(n, p, k, T, S, band)
 
     def counted(*args):
         _TRACES[0] += 1  # Python-executes only while tracing
+        _SHAPES.add((n, p))
         return loop(*args)
 
     return jax.jit(counted, donate_argnums=(10, 11, 12, 13, 14))
@@ -625,7 +789,7 @@ def _build_bisect(n: int, p: int, T: int, S: int, iters: int) -> Callable:
             st0 = init_state(delta, s, b, prefix, order)
             arr, m, _nx, lat_sum, splits, *_rest = loop(
                 delta, s, b, zero, prefix, order, all_bi, p_fix, limits,
-                act, *st0)
+                act, *st0, jnp.zeros(S), jnp.zeros((S, p), dtype=jnp.int64))
             per = arr[:, :, 3].max(axis=1)
             lat = lat_sum + tail
             feas = (per <= p_fix + _EPS) & (lat <= limits + _EPS)
@@ -671,6 +835,7 @@ def _get_bisect(n: int, p: int, T: int, S: int, iters: int) -> Callable:
 
     def counted(*args):
         _TRACES[0] += 1  # Python-executes only while tracing
+        _SHAPES.add((n, p))
         return fn(*args)
 
     return jax.jit(counted)
@@ -678,68 +843,127 @@ def _get_bisect(n: int, p: int, T: int, S: int, iters: int) -> Callable:
 
 def run_fused(state, k: int, bi_mode: np.ndarray, stop: np.ndarray,
               lat_limit: np.ndarray, record: Optional[Callable] = None) -> None:
-    """Run the fused loop over ``state`` (a ``batched._BatchState``), writing
-    final arrays back and replaying per-iteration ``record`` callbacks — a
-    drop-in replacement for the numpy ``_run_loop`` body with O(1) dispatches.
+    """Run the fused loop over ``state`` (a ``batched._BatchState``) on the
+    default device — a drop-in replacement for the numpy ``_run_loop`` body
+    with O(1) dispatches where the device's float64 is IEEE."""
+    n, p = state.pb.n, state.pb.p
+    S = chunk_rows(n, k)
+    band = device_band()
+    run_loop(state, k, bi_mode, stop, lat_limit, record, S,
+             lambda T: _get_loop(n, p, k, T, S, band), band, _DISPATCHES)
+
+
+def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
+             get_program: Callable, band: float, dispatches: list) -> None:
+    """Host driver shared by the fused and sharded engines: run the traced
+    loop ``get_program(T)`` over ``state``'s active rows in chunks of ``S``,
+    counting each dispatch in ``dispatches[0]``.
+
+    ``band == 0``: one dispatch per chunk; the device's final state and
+    per-iteration records are written back as they are.  ``band > 0``
+    (certified loop): the device's decisions are replayed on the host in
+    float64 with the numpy engine's own ``_apply_splits``, so every float is
+    the numpy engine's; rows the device parked take one float64 step of the
+    numpy loop and go back to the device, until no row is active.  Either
+    way ``record`` sees the numpy engine's lockstep sequence.
     """
+    from .batched import _apply_splits, _numpy_loop
+
     pb = state.pb
     B, n, p = pb.B, pb.n, pb.p
     T = min(n - 1, p - 1)
     if T <= 0 or not state.active.any():
         state.active[:] = False
         return
-    S = chunk_rows(n, k)
-    fn = _get_loop(n, p, k, T, S)
+    fn = get_program(T)
     b = np.float64(pb.b)
     bi_mode = np.asarray(bi_mode, dtype=bool)
     stop = np.asarray(stop, dtype=np.float64)
     lat_limit = np.asarray(lat_limit, dtype=np.float64)
-    chunks = []  # (rows, per_rec, lat_rec, acc_rec, t_used)
-    for lo in range(0, B, S):
-        rows = np.arange(lo, min(lo + S, B))
-        pad = S - rows.size
-        sel = np.concatenate([rows, np.zeros(pad, dtype=np.int64)]) if pad else rows
-        act = np.zeros(S, dtype=bool)
-        act[:rows.size] = state.active[rows]
-        _DISPATCHES[0] += 1
-        # the SoA state slices are fresh fancy-index copies, safe to donate
-        out = fn(pb.delta[sel], pb.s[sel], b, np.float64(0.0),
-                 pb.prefix[sel], pb.order[sel].astype(np.int64), bi_mode[sel],
-                 stop[sel], lat_limit[sel], act,
-                 state.arr[sel], state.m[sel], state.next_idx[sel],
-                 state.lat_sum[sel], state.splits[sel])
-        (arr, m, next_idx, lat_sum, splits,
-         per_rec, lat_rec, acc_rec, t_used) = (np.asarray(o) for o in out)
-        r = rows.size
-        state.arr[rows] = arr[:r]
-        state.m[rows] = m[:r]
-        state.next_idx[rows] = next_idx[:r]
-        state.lat_sum[rows] = lat_sum[:r]
-        state.splits[rows] = splits[:r]
-        state.active[rows] = False
-        if record is not None:
-            chunks.append((rows, per_rec[:, :r], lat_rec[:, :r],
-                           acc_rec[:, :r], int(t_used)))
+    scale = np.zeros(B)
+    sbits = np.zeros((B, p), dtype=np.int64)
+    if band:
+        # bounds every period/latency quantity of the row: all work on the
+        # slowest processor plus every transfer twice
+        scale = ((pb.prefix[:, n] - pb.prefix[:, 0]) / pb.s.min(axis=1)
+                 + (n + 2) * pb.delta.max(axis=1) / b)
+        sbits = np.ascontiguousarray(pb.s).view(np.int64)
+    base = state.splits.copy()
+    steps = []       # (rows, split index per row, period, latency)
+
+    def note(rows, pers, lats):
+        steps.append((rows, state.splits[rows] - base[rows] - 1, pers, lats))
+
+    todo = np.nonzero(state.active)[0]
+    while todo.size:
+        parked = []
+        for lo in range(0, todo.size, S):
+            rows = todo[lo:lo + S]
+            r = rows.size
+            sel = np.concatenate([rows, np.repeat(rows[:1], S - r)])
+            act = np.zeros(S, dtype=bool)
+            act[:r] = True
+            dispatches[0] += 1
+            # the SoA state slices are fresh fancy-index copies, safe to donate
+            out = fn(pb.delta[sel], pb.s[sel], b, np.float64(0.0),
+                     pb.prefix[sel], pb.order[sel].astype(np.int64),
+                     bi_mode[sel], stop[sel], lat_limit[sel], act,
+                     state.arr[sel], state.m[sel], state.next_idx[sel],
+                     state.lat_sum[sel], state.splits[sel],
+                     scale[sel], sbits[sel])
+            (arr, m, next_idx, lat_sum, splits, park,
+             per_rec, lat_rec, acc_rec, dec_rec, t_used) = (
+                np.asarray(o) for o in out)
+            t_used = int(t_used.max())
+            state.active[rows] = False
+            if not band:
+                state.arr[rows] = arr[:r]
+                state.m[rows] = m[:r]
+                state.next_idx[rows] = next_idx[:r]
+                state.lat_sum[rows] = lat_sum[:r]
+                state.splits[rows] = splits[:r]
+                for t in range(t_used):
+                    a = acc_rec[t, :r]
+                    if a.any():
+                        steps.append((rows[a], np.full(a.sum(), t),
+                                      per_rec[t, :r][a], lat_rec[t, :r][a]))
+                continue
+            for t in range(t_used):
+                a = acc_rec[t, :r]
+                if not a.any():
+                    continue
+                acc = rows[a]
+                dec = dec_rec[t, :r][a]
+                _DECISIONS["device"] += acc.size
+                _apply_splits(state, acc, dec[:, 0], dec[:, 1:4],
+                              dec[:, 4:7], dec[:, 7:10], dec[:, 10],
+                              dec[:, 11])
+                note(acc, state.arr[acc, :, 3].max(axis=1),
+                     state.lat_sum[acc] + state.tail[acc])
+            parked.append(rows[park[:r]])
+        todo = np.concatenate(parked) if parked else todo[:0]
+        if todo.size:
+            # the decisions the device could not certify, made in float64
+            _DECISIONS["host"] += todo.size
+            state.active[todo] = True
+            _numpy_loop(state, todo, k, bi_mode, stop, lat_limit, "numpy",
+                        note, max_iters=1)
+            todo = todo[state.active[todo]]
     if record is None:
         return
-    # Replay records in global lockstep order: a row's s-th accepted split
-    # always lands at iteration s regardless of which rows share its chunk,
-    # so merging chunk records per iteration reproduces the numpy engine's
-    # record sequence exactly.
-    t_max = max((t for *_, t in chunks), default=0)
-    for t in range(t_max):
-        rsel, pers, lats = [], [], []
-        for rows, per_rec, lat_rec, acc_rec, t_used in chunks:
-            if t >= t_used:
-                continue
-            a = acc_rec[t]
-            if a.any():
-                rsel.append(rows[a])
-                pers.append(per_rec[t][a])
-                lats.append(lat_rec[t][a])
-        if rsel:
-            record(np.concatenate(rsel), np.concatenate(pers),
-                   np.concatenate(lats))
+    # Replay in the numpy engine's lockstep order: a row's s-th accepted
+    # split lands at iteration s, whichever chunk, round or side decided it.
+    if not steps:
+        return
+    rows = np.concatenate([x[0] for x in steps])
+    it = np.concatenate([x[1] for x in steps])
+    pers = np.concatenate([x[2] for x in steps])
+    lats = np.concatenate([x[3] for x in steps])
+    order = np.lexsort((rows, it))
+    rows, it, pers, lats = rows[order], it[order], pers[order], lats[order]
+    for t in np.unique(it):
+        sel = it == t
+        record(rows[sel], pers[sel], lats[sel])
 
 
 def run_fused_bisection(pb, p_fix: np.ndarray, lo: np.ndarray, hi: np.ndarray,

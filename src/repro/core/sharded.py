@@ -55,12 +55,14 @@ from .fused import chunk_rows
 
 __all__ = ["sharded_available", "device_count", "run_sharded",
            "run_sharded_bisection", "trace_count", "reset_trace_count",
-           "dispatch_count", "reset_dispatch_count"]
+           "dispatch_count", "reset_dispatch_count", "output_devices"]
 
 # traces / dispatches of the SPMD programs, mirroring fused.py's counters
 # (the shared bucket branches still count into fused._BUCKET_TRACES).
 _TRACES = [0]
 _DISPATCHES = [0]
+# devices the outputs of the latest dispatch are laid out over
+_OUT_DEVICES = [0]
 
 
 def trace_count() -> int:
@@ -83,13 +85,18 @@ def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
 
 
+def output_devices() -> int:
+    """Devices spanned by the outputs of the latest SPMD dispatch (0 before
+    the first): the check that a campaign really ran across the mesh."""
+    return _OUT_DEVICES[0]
+
+
 def sharded_available() -> bool:
     try:
-        import jax  # noqa: F401
-        from jax.experimental.shard_map import shard_map  # noqa: F401
+        import jax
     except Exception:  # pragma: no cover - jax is baked into the image
         return False
-    return True
+    return hasattr(jax, "shard_map")
 
 
 def device_count() -> int:
@@ -108,17 +115,18 @@ def _mesh():
     return Mesh(np.array(jax.devices()), ("i",))
 
 
-def _shard_wrap(fn: Callable, n_state_out: int, mesh) -> Callable:
+def _shard_wrap(fn: Callable, n_state_out: int, n_rec_out: int,
+                mesh) -> Callable:
     """Wrap an unjitted per-shard program in ``shard_map`` over the row axis.
 
-    ``fn(*args) -> (*state..., per_rec, lat_rec, acc_rec, t)`` where the
-    state outputs are row-leading, the records are (T, S_local), and ``t``
-    is a per-shard scalar.  Scalar inputs (0-d) are replicated; every other
-    input is sharded along its leading axis.  The per-shard iteration count
-    comes back broadcast per-row so the host can take the global max.
+    ``fn(*args) -> (*state..., *records..., t)`` where the state outputs are
+    row-leading, the records are (T, S_local, ...), and ``t`` is a per-shard
+    scalar.  Scalar inputs (0-d) are replicated; every other input is
+    sharded along its leading axis.  The per-shard iteration count comes
+    back broadcast per-row so the host can take the global max.
     """
+    import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     row = P("i")
@@ -126,33 +134,41 @@ def _shard_wrap(fn: Callable, n_state_out: int, mesh) -> Callable:
 
     def local(*args):
         out = fn(*args)
-        state, trecs, t = out[:n_state_out], out[n_state_out:-1], out[-1]
-        t_rows = jnp.full((state[0].shape[0],), t, dtype=jnp.int64)
-        return (*state, *trecs, t_rows)
+        t = out[-1]
+        t_rows = jnp.full((out[0].shape[0],), t, dtype=jnp.int64)
+        return (*out[:-1], t_rows)
 
     def specs_for(args):
         return tuple(P() if np.ndim(a) == 0 else row for a in args)
 
     def wrapped(*args):
         _TRACES[0] += 1  # Python-executes only while tracing
-        body = shard_map(local, mesh=mesh, in_specs=specs_for(args),
-                         out_specs=(row,) * n_state_out + (rec,) * 3 + (row,),
-                         check_rep=False)
+        body = jax.shard_map(local, mesh=mesh, in_specs=specs_for(args),
+                             out_specs=(row,) * n_state_out
+                             + (rec,) * n_rec_out + (row,), check_vma=False)
         return body(*args)
 
     return wrapped
 
 
 @functools.lru_cache(maxsize=None)
-def _get_sharded_loop(n: int, p: int, k: int, T: int, S_local: int) -> Callable:
+def _get_sharded_loop(n: int, p: int, k: int, T: int, S_local: int,
+                      band: float = 0.0) -> Callable:
     """The jitted SPMD fused loop for static shape (n, p, k): per-shard rows
     ``S_local``, global rows ``S_local * device_count()``.  SoA state buffers
     donated, exactly like ``fused._get_loop``."""
     import jax
 
-    _init_state, loop = fused._build_loop(n, p, k, T, S_local)
-    wrapped = _shard_wrap(loop, n_state_out=5, mesh=_mesh())
-    return jax.jit(wrapped, donate_argnums=(10, 11, 12, 13, 14))
+    _init_state, loop = fused._build_loop(n, p, k, T, S_local, band)
+    wrapped = _shard_wrap(loop, n_state_out=6, n_rec_out=4, mesh=_mesh())
+    jitted = jax.jit(wrapped, donate_argnums=(10, 11, 12, 13, 14))
+
+    def run(*args):
+        out = jitted(*args)
+        _OUT_DEVICES[0] = len(out[0].sharding.device_set)
+        return out
+
+    return run
 
 
 @functools.lru_cache(maxsize=None)
@@ -161,7 +177,6 @@ def _get_sharded_bisect(n: int, p: int, T: int, S_local: int,
     """The jitted SPMD H4 bisection (probe0 + ``lax.scan``) — the per-shard
     program is ``fused._build_bisect``'s, sharded over the row axis."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     fn = fused._build_bisect(n, p, T, S_local, iters)
@@ -171,8 +186,8 @@ def _get_sharded_bisect(n: int, p: int, T: int, S_local: int,
     def wrapped(*args):
         _TRACES[0] += 1  # Python-executes only while tracing
         in_specs = tuple(P() if np.ndim(a) == 0 else row for a in args)
-        body = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=(row,) * 11, check_rep=False)
+        body = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=(row,) * 11, check_vma=False)
         return body(*args)
 
     return jax.jit(wrapped)
@@ -182,69 +197,19 @@ def run_sharded(state, k: int, bi_mode: np.ndarray, stop: np.ndarray,
                 lat_limit: np.ndarray, record: Optional[Callable] = None) -> None:
     """Run the fused loop over ``state`` (a ``batched._BatchState``) as one
     SPMD program per global row-chunk, sharded across all devices.  Drop-in
-    replacement for :func:`fused.run_fused` — same write-back, same record
-    replay, bit-identical floats on any device count.
+    replacement for :func:`fused.run_fused` — same driver
+    (:func:`fused.run_loop`), same write-back and record replay,
+    bit-identical floats on any device count.  Padding rows of a chunk
+    start INACTIVE, so they are live in no iteration and their state is
+    never written back.
     """
-    pb = state.pb
-    B, n, p = pb.B, pb.n, pb.p
-    T = min(n - 1, p - 1)
-    if T <= 0 or not state.active.any():
-        state.active[:] = False
-        return
+    n, p = state.pb.n, state.pb.p
     D = device_count()
     S_local = chunk_rows(n, k)
-    S = S_local * D
-    fn = _get_sharded_loop(n, p, k, T, S_local)
-    b = np.float64(pb.b)
-    bi_mode = np.asarray(bi_mode, dtype=bool)
-    stop = np.asarray(stop, dtype=np.float64)
-    lat_limit = np.asarray(lat_limit, dtype=np.float64)
-    chunks = []  # (rows, per_rec, lat_rec, acc_rec, t_used)
-    for lo in range(0, B, S):
-        rows = np.arange(lo, min(lo + S, B))
-        pad = S - rows.size
-        # padding rows carry row 0's instance data but start INACTIVE, so
-        # they are live in no iteration and their state is never written back
-        sel = np.concatenate([rows, np.zeros(pad, dtype=np.int64)]) if pad else rows
-        act = np.zeros(S, dtype=bool)
-        act[:rows.size] = state.active[rows]
-        _DISPATCHES[0] += 1
-        # the SoA state slices are fresh fancy-index copies, safe to donate
-        out = fn(pb.delta[sel], pb.s[sel], b, np.float64(0.0),
-                 pb.prefix[sel], pb.order[sel].astype(np.int64), bi_mode[sel],
-                 stop[sel], lat_limit[sel], act,
-                 state.arr[sel], state.m[sel], state.next_idx[sel],
-                 state.lat_sum[sel], state.splits[sel])
-        (arr, m, next_idx, lat_sum, splits,
-         per_rec, lat_rec, acc_rec, t_rows) = (np.asarray(o) for o in out)
-        r = rows.size
-        state.arr[rows] = arr[:r]
-        state.m[rows] = m[:r]
-        state.next_idx[rows] = next_idx[:r]
-        state.lat_sum[rows] = lat_sum[:r]
-        state.splits[rows] = splits[:r]
-        state.active[rows] = False
-        if record is not None:
-            chunks.append((rows, per_rec[:, :r], lat_rec[:, :r],
-                           acc_rec[:, :r], int(t_rows.max())))
-    if record is None:
-        return
-    # Replay records in global lockstep order (a row's s-th accepted split
-    # lands at iteration s on every shard — see fused.run_fused).
-    t_max = max((t for *_, t in chunks), default=0)
-    for t in range(t_max):
-        rsel, pers, lats = [], [], []
-        for rows, per_rec, lat_rec, acc_rec, t_used in chunks:
-            if t >= t_used:
-                continue
-            a = acc_rec[t]
-            if a.any():
-                rsel.append(rows[a])
-                pers.append(per_rec[t][a])
-                lats.append(lat_rec[t][a])
-        if rsel:
-            record(np.concatenate(rsel), np.concatenate(pers),
-                   np.concatenate(lats))
+    band = fused.device_band()
+    fused.run_loop(state, k, bi_mode, stop, lat_limit, record, S_local * D,
+                   lambda T: _get_sharded_loop(n, p, k, T, S_local, band),
+                   band, _DISPATCHES)
 
 
 def run_sharded_bisection(pb, p_fix: np.ndarray, lo: np.ndarray,
@@ -285,6 +250,7 @@ def run_sharded_bisection(pb, p_fix: np.ndarray, lo: np.ndarray,
         res = fn(pb.delta[sel], pb.s[sel], b, np.float64(0.0),
                  pb.prefix[sel], pb.order[sel].astype(np.int64), p_fix[sel],
                  lo[sel], hi[sel], act)
+        _OUT_DEVICES[0] = len(res[0].sharding.device_set)
         for name, val in zip(names, res):
             out[name][rows] = np.asarray(val)[:rows.size]
     return out

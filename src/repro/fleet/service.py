@@ -238,6 +238,9 @@ class ReplanService:
         self._below_since: dict = {} # iid -> tick it dipped below the floor
         self.quarantine_strikes: dict = {}   # digest -> failed-round count
         self.quarantined: set = set()        # digests pinned to last valid plan
+        # the exception behind the latest scalar fallback, so a caller that
+        # requires the batched path (e.g. on a chip) can say why it was left
+        self.last_solve_error: Optional[BaseException] = None
         self.journal = (Journal(journal) if isinstance(journal,
                                                        (str, pathlib.Path))
                         else journal)
@@ -386,7 +389,8 @@ class ReplanService:
                 b)
             try:
                 results = list(self.supervisor.solve(pb))
-            except Exception:  # noqa: BLE001 — degrade, don't die mid-tick
+            except Exception as exc:  # noqa: BLE001 — degrade, don't die
+                self.last_solve_error = exc
                 for digest, st in entries:
                     try:
                         res = min_period_exhaustive(

@@ -57,6 +57,10 @@ from .transport import (FrameError, FrameReader, decode_results, encode_frame,
 _SRC_DIR = pathlib.Path(__file__).resolve().parents[2]
 
 
+#: Solve backends that run on the accelerator (one process per chip).
+DEVICE_BACKENDS = ("jax", "pallas", "fused", "sharded")
+
+
 class WorkerFailed(RuntimeError):
     """A solve group failed on every attempt; the last cause is chained."""
 
@@ -546,7 +550,14 @@ def subprocess_supervisor(*, backend: str = "numpy", workers: int = 1,
     remaining keywords configure the transport (``chaos``, ``term_grace``,
     ``ignore_sigterm``, wedge test hooks) or pass through to
     :class:`Supervisor` (``max_attempts``, ``backoff_base``, ...).
+
+    A device backend gets one worker: a chip belongs to one process at a
+    time, so a second child would fail or hang opening it.
     """
+    if backend in DEVICE_BACKENDS and workers > 1:
+        raise ValueError(
+            f"backend={backend!r} runs on the accelerator, which one process "
+            f"holds at a time; use workers=1, not {workers}")
     worker_cls = functools.partial(
         SubprocessWorker, backend=backend, chaos=chaos, term_grace=term_grace,
         heartbeat_interval=heartbeat_interval, ignore_sigterm=ignore_sigterm,

@@ -26,8 +26,10 @@ to the numpy kernels on every live lane.  Skipped tiles are zero-filled;
 callers mask them out of candidate selection by the same validity masks that
 already exclude them on the numpy path, so heuristic outputs are identical
 (asserted by the ``pallas`` column of tests/test_engine_equivalence.py).
-Out of interpret mode the kernels compile for TPU/GPU, where the float64
-contract is out of scope (devices score in their native dtype).
+Out of interpret mode the kernels compile for TPU/GPU and score in float32
+(Mosaic has no float64), so the float64 contract is out of scope there; the
+per-row ``need`` bounds and every block index are int32 on both paths, which
+is what lets them compile while the process has ``jax_enable_x64`` on.
 
 Selected behind ``repro.core.heuristics.score_kernels("pallas")`` —
 ``repro.core.batched`` exposes it as ``backend="pallas"``.
@@ -48,6 +50,17 @@ def _interpret() -> bool:
     """Interpret (emulate) off-device: CPU runs op-by-op in float64, which is
     what the bit-identity contract is asserted on."""
     return jax.default_backend() not in ("tpu", "gpu")
+
+
+def _score_dtype(interpret: bool, dtype):
+    """Dtype the kernels score in: the caller's (float64) in interpret mode,
+    float32 when compiled for a device."""
+    return dtype if interpret else jnp.float32
+
+
+# block indices must be int32 for Mosaic: a bare literal 0 in an index map
+# would trace as int64 under jax_enable_x64
+_I0 = np.int32(0)
 
 
 def _ensure_x64() -> None:
@@ -125,8 +138,8 @@ def _score2_call(pre_d1, pre_C, pre_e, del_d1, del_C, del_e, b, inv_j, inv_p,
     need_p = jnp.pad(need.reshape(A, 1), pad_c)
     scal = [jnp.reshape(x, (1, 1)) for x in (b, zero)]
     lanespec = pl.BlockSpec((block_a, block_k), lambda i, j: (i, j))
-    colspec = pl.BlockSpec((block_a, 1), lambda i, j: (i, 0))
-    scalspec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    colspec = pl.BlockSpec((block_a, 1), lambda i, j: (i, _I0))
+    scalspec = pl.BlockSpec((1, 1), lambda i, j: (_I0, _I0))
     outs = pl.pallas_call(
         functools.partial(_score2_kernel, block_k=block_k),
         grid=(Ap // block_a, Kp // block_k),
@@ -158,10 +171,11 @@ def score_2way_pallas(pre_d1, pre_C, pre_e, delta_d1, delta_C, delta_e, b,
         interpret = _interpret()
     if need is None:
         need = np.full(A, K)
-    return _score2_call(pre_d1, pre_C, pre_e, delta_d1, delta_C, delta_e,
-                        jnp.asarray(b, pre_C.dtype), inv_j, inv_p,
-                        jnp.asarray(zero, pre_C.dtype),
-                        jnp.asarray(need, jnp.int64), interpret,
+    dt = _score_dtype(interpret, pre_C.dtype)
+    f = functools.partial(jnp.asarray, dtype=dt)
+    return _score2_call(f(pre_d1), f(pre_C), f(pre_e), f(delta_d1),
+                        f(delta_C), f(delta_e), f(b), f(inv_j), f(inv_p),
+                        f(zero), jnp.asarray(need, jnp.int32), interpret,
                         int(block_a), int(block_k))
 
 
@@ -208,19 +222,20 @@ def _score3_call(dI, W, dO, invp, base_term, zero, need, interpret,
     invp_p = jnp.pad(invp, ((0, Ap - A), (0, 0), (0, 0)))
     base_p = jnp.pad(base_term.reshape(A, 1), ((0, Ap - A), (0, 0)))
     need_p = jnp.pad(need.reshape(A, 1), ((0, Ap - A), (0, 0)))
-    lanespec = pl.BlockSpec((block_a, 3, block_k), lambda i, j: (i, 0, j))
-    permspec = pl.BlockSpec((block_a, 6, 3), lambda i, j: (i, 0, 0))
-    colspec = pl.BlockSpec((block_a, 1), lambda i, j: (i, 0))
-    scalspec = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    lanespec = pl.BlockSpec((block_a, 3, block_k), lambda i, j: (i, _I0, j))
+    permspec = pl.BlockSpec((block_a, 6, 3), lambda i, j: (i, _I0, _I0))
+    colspec = pl.BlockSpec((block_a, 1), lambda i, j: (i, _I0))
+    scalspec = pl.BlockSpec((1, 1), lambda i, j: (_I0, _I0))
     outs = pl.pallas_call(
         functools.partial(_score3_kernel, block_k=block_k),
         grid=(Ap // block_a, Kp // block_k),
         in_specs=[lanespec, lanespec, lanespec, permspec, colspec, scalspec,
                   colspec],
         out_specs=[
-            pl.BlockSpec((block_a, 6, 3, block_k), lambda i, j: (i, 0, 0, j)),
-            pl.BlockSpec((block_a, 6, block_k), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((block_a, 6, block_k), lambda i, j: (i, 0, j)),
+            pl.BlockSpec((block_a, 6, 3, block_k),
+                         lambda i, j: (i, _I0, _I0, j)),
+            pl.BlockSpec((block_a, 6, block_k), lambda i, j: (i, _I0, j)),
+            pl.BlockSpec((block_a, 6, block_k), lambda i, j: (i, _I0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Ap, 6, 3, Kp), dI.dtype),
@@ -249,9 +264,9 @@ def score_3way_pallas(dI, W, dO, invp, base_term, *, zero=0.0, need=None,
         interpret = _interpret()
     if need is None:
         need = np.full(A, K)
-    return _score3_call(dI.reshape(A, 3, K), jnp.asarray(W).reshape(A, 3, K),
-                        jnp.asarray(dO).reshape(A, 3, K),
-                        jnp.asarray(invp).reshape(A, 6, 3),
-                        jnp.asarray(base_term), jnp.asarray(zero, dI.dtype),
-                        jnp.asarray(need, jnp.int64), interpret,
-                        int(block_a), int(block_k))
+    dt = _score_dtype(interpret, dI.dtype)
+    f = functools.partial(jnp.asarray, dtype=dt)
+    return _score3_call(f(dI).reshape(A, 3, K), f(W).reshape(A, 3, K),
+                        f(dO).reshape(A, 3, K), f(invp).reshape(A, 6, 3),
+                        f(base_term), f(zero), jnp.asarray(need, jnp.int32),
+                        interpret, int(block_a), int(block_k))

@@ -23,7 +23,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from .common import ModelConfig, abstract_mesh
+from .common import ModelConfig
 from .layers import apply_rope, dense_init, rms_norm, shard
 
 NEG_INF = -1e30
@@ -124,8 +124,8 @@ def _block_pairs(nq: int, nk: int, bq: int, bk: int, causal: bool,
 
 
 def _mesh_model_size() -> int:
-    am = abstract_mesh()
-    if am is None or am.empty or "model" not in am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or "model" not in am.axis_names:
         return 1
     return am.shape["model"]
 

@@ -5,16 +5,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
-
-
-def abstract_mesh():
-    """``jax.sharding.get_abstract_mesh()`` when the installed jax has it
-    (>= 0.5); ``None`` otherwise — older jax has no ambient abstract mesh, so
-    every call site's no-mesh path is the correct behavior there."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    return fn() if fn is not None else None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .common import ModelConfig, abstract_mesh
+from .common import ModelConfig
 
 # ---------------------------------------------------------------------------
 # Logical sharding
@@ -52,8 +52,8 @@ def shard(x: jax.Array, *logical_axes) -> jax.Array:
     """Constrain ``x``'s sharding by logical axis names; no-op without a mesh.
     Axes whose dimension is not divisible by the mesh-axis size are dropped
     (uneven constraints trigger GSPMD resharding storms)."""
-    am = abstract_mesh()
-    if am is None or am.empty:
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
         return x
     mesh_axes = set(am.axis_names) - set(getattr(am, "manual_axes", ()) or ())
     entries = []

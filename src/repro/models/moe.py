@@ -19,7 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .common import ModelConfig, abstract_mesh
+from .common import ModelConfig
 from .layers import dense_init, init_mlp, mlp, shard
 
 
@@ -42,9 +42,9 @@ def _moe_groups(N: int, E: int, B: int) -> int:
     """Number of dispatch groups: one per data shard when it divides the
     batch (locality by construction — sort/scatter never cross shards),
     clamped so each group still feeds every expert a reasonable slice."""
-    am = abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     dsize = 1
-    if am is not None and not am.empty:
+    if not am.empty:
         for a in ("pod", "data"):
             if a in am.axis_names:
                 dsize *= am.shape[a]
@@ -77,9 +77,9 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple:
     flat = x.reshape(G, n, d)
     flat = shard(flat, "batch", None, "d_model")
 
-    am = abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     data_axes = tuple(a for a in ("pod", "data")
-                      if am is not None and not am.empty and a in am.axis_names)
+                      if not am.empty and a in am.axis_names)
     dsize = 1
     for a in data_axes:
         dsize *= am.shape[a]

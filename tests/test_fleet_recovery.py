@@ -390,6 +390,14 @@ def _inline_reference(pb):
     return batched_min_period(pb, "numpy")
 
 
+@pytest.mark.parametrize("backend", ["jax", "pallas", "fused", "sharded"])
+def test_device_backend_pool_of_two_is_refused(backend):
+    """A chip belongs to one process: two workers on a device backend would
+    race for it, so construction refuses before any child is spawned."""
+    with pytest.raises(ValueError, match="workers=1"):
+        subprocess_supervisor(backend=backend, workers=2)
+
+
 @pytest.mark.slow
 def test_subprocess_worker_is_bit_identical_to_inline():
     pb = _batch(seed=21)
