@@ -78,11 +78,12 @@ from typing import Callable, Optional
 import numpy as np
 
 from .heuristics import _EPS, score_2way_kernel, score_3way_kernel
+from .spans import recording, span
 
 __all__ = ["fused_available", "run_fused", "run_fused_bisection",
            "trace_count", "reset_trace_count", "traced_shapes",
            "decision_counts", "device_band", "run_loop",
-           "dispatch_count", "reset_dispatch_count",
+           "dispatch_count", "reset_dispatch_count", "transfer_bytes",
            "bucket_trace_count", "reset_bucket_trace_count",
            "bucket_sizes", "bucket_index", "trace_budget",
            "enable_persistent_cache"]
@@ -104,6 +105,9 @@ _DISPATCHES = [0]
 # decisions of certified loops since the last dispatch-count reset: splits
 # the device decided, and parked row-steps the host re-decided in float64
 _DECISIONS = {"device": 0, "host": 0}
+# bytes handed to this engine's jitted programs (every argument of every
+# dispatch) and copied back (every output) since the last dispatch-count reset
+_TRANSFER = {"to_device": 0, "to_host": 0}
 
 # Relative error bound, against each row's magnitude scale, that certified
 # loops allow the device arithmetic.  TPU float64 is emulated with float32
@@ -175,6 +179,17 @@ def dispatch_count() -> int:
 def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
     _DECISIONS.update(device=0, host=0)
+    _TRANSFER.update(to_device=0, to_host=0)
+
+
+def transfer_bytes() -> dict:
+    """Bytes over the host link since :func:`reset_dispatch_count`:
+    ``to_device`` the ``nbytes`` of every argument of every call of this
+    engine's programs (:func:`run_fused`, :func:`run_fused_bisection`),
+    ``to_host`` those of every output, so that bytes over
+    :func:`dispatch_count` are per call.  The sharded engine counts its own
+    (``sharded.transfer_bytes``)."""
+    return dict(_TRANSFER)
 
 
 def decision_counts() -> dict:
@@ -746,13 +761,25 @@ def _get_loop(n: int, p: int, k: int, T: int, S: int,
     import jax
 
     _init_state, loop = _build_loop(n, p, k, T, S, band)
+    return jax.jit(_named(f"fused_loop_k{k}", n, p, loop),
+                   donate_argnums=(10, 11, 12, 13, 14))
 
-    def counted(*args):
+
+def _named(name: str, n: int, p: int, fn: Callable) -> Callable:
+    """``fn`` as the traced program ``name``: the jitted function carries
+    the name (so its XLA module and dispatch events in a profile tell the
+    programs apart) and its operations sit under ``jax.named_scope(name)``.
+    Each trace is counted (:func:`trace_count`, :func:`traced_shapes`)."""
+    import jax
+
+    def program(*args):
         _TRACES[0] += 1  # Python-executes only while tracing
         _SHAPES.add((n, p))
-        return loop(*args)
+        with jax.named_scope(name):
+            return fn(*args)
 
-    return jax.jit(counted, donate_argnums=(10, 11, 12, 13, 14))
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def _build_bisect(n: int, p: int, T: int, S: int, iters: int) -> Callable:
@@ -831,14 +858,8 @@ def _get_bisect(n: int, p: int, T: int, S: int, iters: int) -> Callable:
     :func:`_build_bisect` for the program's contract)."""
     import jax
 
-    fn = _build_bisect(n, p, T, S, iters)
-
-    def counted(*args):
-        _TRACES[0] += 1  # Python-executes only while tracing
-        _SHAPES.add((n, p))
-        return fn(*args)
-
-    return jax.jit(counted)
+    return jax.jit(_named("fused_bisect", n, p,
+                          _build_bisect(n, p, T, S, iters)))
 
 
 def run_fused(state, k: int, bi_mode: np.ndarray, stop: np.ndarray,
@@ -850,14 +871,17 @@ def run_fused(state, k: int, bi_mode: np.ndarray, stop: np.ndarray,
     S = chunk_rows(n, k)
     band = device_band()
     run_loop(state, k, bi_mode, stop, lat_limit, record, S,
-             lambda T: _get_loop(n, p, k, T, S, band), band, _DISPATCHES)
+             lambda T: _get_loop(n, p, k, T, S, band), band, _DISPATCHES,
+             _TRANSFER)
 
 
 def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
-             get_program: Callable, band: float, dispatches: list) -> None:
+             get_program: Callable, band: float, dispatches: list,
+             transfer: dict) -> None:
     """Host driver shared by the fused and sharded engines: run the traced
     loop ``get_program(T)`` over ``state``'s active rows in chunks of ``S``,
-    counting each dispatch in ``dispatches[0]``.
+    counting each dispatch in ``dispatches[0]`` and the bytes of its
+    arguments and outputs in ``transfer`` (the engine's own counters).
 
     ``band == 0``: one dispatch per chunk; the device's final state and
     per-iteration records are written back as they are.  ``band > 0``
@@ -866,6 +890,12 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
     the numpy engine's; rows the device parked take one float64 step of the
     numpy loop and go back to the device, until no row is active.  Either
     way ``record`` sees the numpy engine's lockstep sequence.
+
+    Each dispatch runs under the spans ``fused.launch`` and ``fused.fetch``
+    (with ``fused.wait`` split off while a profiler records), the host's
+    use of its outputs under ``fused.replay``, each parked round under
+    ``fused.parked_step`` and the final replay under ``fused.record``
+    (:mod:`repro.core.spans`).
     """
     from .batched import _apply_splits, _numpy_loop
 
@@ -898,72 +928,102 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
     while todo.size:
         parked = []
         for lo in range(0, todo.size, S):
-            rows = todo[lo:lo + S]
-            r = rows.size
-            sel = np.concatenate([rows, np.repeat(rows[:1], S - r)])
-            act = np.zeros(S, dtype=bool)
-            act[:r] = True
-            dispatches[0] += 1
-            # the SoA state slices are fresh fancy-index copies, safe to donate
-            out = fn(pb.delta[sel], pb.s[sel], b, np.float64(0.0),
-                     pb.prefix[sel], pb.order[sel].astype(np.int64),
-                     bi_mode[sel], stop[sel], lat_limit[sel], act,
-                     state.arr[sel], state.m[sel], state.next_idx[sel],
-                     state.lat_sum[sel], state.splits[sel],
-                     scale[sel], sbits[sel])
-            (arr, m, next_idx, lat_sum, splits, park,
-             per_rec, lat_rec, acc_rec, dec_rec, t_used) = (
-                np.asarray(o) for o in out)
-            t_used = int(t_used.max())
-            state.active[rows] = False
-            if not band:
-                state.arr[rows] = arr[:r]
-                state.m[rows] = m[:r]
-                state.next_idx[rows] = next_idx[:r]
-                state.lat_sum[rows] = lat_sum[:r]
-                state.splits[rows] = splits[:r]
+            with span("fused.launch"):
+                rows = todo[lo:lo + S]
+                r = rows.size
+                sel = np.concatenate([rows, np.repeat(rows[:1], S - r)])
+                act = np.zeros(S, dtype=bool)
+                act[:r] = True
+                dispatches[0] += 1
+                # the SoA state slices are fresh fancy-index copies, safe to
+                # donate
+                out = _call(fn, transfer, pb.delta[sel], pb.s[sel], b,
+                            np.float64(0.0), pb.prefix[sel],
+                            pb.order[sel].astype(np.int64), bi_mode[sel],
+                            stop[sel], lat_limit[sel], act, state.arr[sel],
+                            state.m[sel], state.next_idx[sel],
+                            state.lat_sum[sel], state.splits[sel],
+                            scale[sel], sbits[sel])
+            (arr, m, next_idx, lat_sum, splits, park, per_rec, lat_rec,
+             acc_rec, dec_rec, t_used) = _fetch(out, transfer)
+            with span("fused.replay"):
+                t_used = int(t_used.max())
+                state.active[rows] = False
+                if not band:
+                    state.arr[rows] = arr[:r]
+                    state.m[rows] = m[:r]
+                    state.next_idx[rows] = next_idx[:r]
+                    state.lat_sum[rows] = lat_sum[:r]
+                    state.splits[rows] = splits[:r]
+                    for t in range(t_used):
+                        a = acc_rec[t, :r]
+                        if a.any():
+                            steps.append((rows[a], np.full(a.sum(), t),
+                                          per_rec[t, :r][a],
+                                          lat_rec[t, :r][a]))
+                    continue
                 for t in range(t_used):
                     a = acc_rec[t, :r]
-                    if a.any():
-                        steps.append((rows[a], np.full(a.sum(), t),
-                                      per_rec[t, :r][a], lat_rec[t, :r][a]))
-                continue
-            for t in range(t_used):
-                a = acc_rec[t, :r]
-                if not a.any():
-                    continue
-                acc = rows[a]
-                dec = dec_rec[t, :r][a]
-                _DECISIONS["device"] += acc.size
-                _apply_splits(state, acc, dec[:, 0], dec[:, 1:4],
-                              dec[:, 4:7], dec[:, 7:10], dec[:, 10],
-                              dec[:, 11])
-                note(acc, state.arr[acc, :, 3].max(axis=1),
-                     state.lat_sum[acc] + state.tail[acc])
-            parked.append(rows[park[:r]])
+                    if not a.any():
+                        continue
+                    acc = rows[a]
+                    dec = dec_rec[t, :r][a]
+                    _DECISIONS["device"] += acc.size
+                    _apply_splits(state, acc, dec[:, 0], dec[:, 1:4],
+                                  dec[:, 4:7], dec[:, 7:10], dec[:, 10],
+                                  dec[:, 11])
+                    note(acc, state.arr[acc, :, 3].max(axis=1),
+                         state.lat_sum[acc] + state.tail[acc])
+                parked.append(rows[park[:r]])
         todo = np.concatenate(parked) if parked else todo[:0]
         if todo.size:
             # the decisions the device could not certify, made in float64
-            _DECISIONS["host"] += todo.size
-            state.active[todo] = True
-            _numpy_loop(state, todo, k, bi_mode, stop, lat_limit, "numpy",
-                        note, max_iters=1)
-            todo = todo[state.active[todo]]
-    if record is None:
+            with span("fused.parked_step"):
+                _DECISIONS["host"] += todo.size
+                state.active[todo] = True
+                _numpy_loop(state, todo, k, bi_mode, stop, lat_limit,
+                            "numpy", note, max_iters=1)
+                todo = todo[state.active[todo]]
+    if record is None or not steps:
         return
     # Replay in the numpy engine's lockstep order: a row's s-th accepted
     # split lands at iteration s, whichever chunk, round or side decided it.
-    if not steps:
-        return
-    rows = np.concatenate([x[0] for x in steps])
-    it = np.concatenate([x[1] for x in steps])
-    pers = np.concatenate([x[2] for x in steps])
-    lats = np.concatenate([x[3] for x in steps])
-    order = np.lexsort((rows, it))
-    rows, it, pers, lats = rows[order], it[order], pers[order], lats[order]
-    for t in np.unique(it):
-        sel = it == t
-        record(rows[sel], pers[sel], lats[sel])
+    with span("fused.record"):
+        rows = np.concatenate([x[0] for x in steps])
+        it = np.concatenate([x[1] for x in steps])
+        pers = np.concatenate([x[2] for x in steps])
+        lats = np.concatenate([x[3] for x in steps])
+        order = np.lexsort((rows, it))
+        rows, it, pers, lats = (rows[order], it[order], pers[order],
+                                lats[order])
+        for t in np.unique(it):
+            sel = it == t
+            record(rows[sel], pers[sel], lats[sel])
+
+
+def _call(fn: Callable, transfer: dict, *args):
+    """``fn(*args)``, the ``nbytes`` of its arguments counted into
+    ``transfer["to_device"]``; the host arrays live only for the call, as
+    when they are passed to ``fn`` directly."""
+    transfer["to_device"] += sum(a.nbytes for a in args)
+    return fn(*args)
+
+
+def _fetch(out, transfer: dict) -> list:
+    """A program call's outputs on the host (``fused.fetch``), their
+    ``nbytes`` counted into ``transfer["to_host"]``.  While a profiler
+    records (:func:`repro.core.spans.recording`) the wait for the device is
+    split off first as ``fused.wait``; otherwise the first conversion waits,
+    as a plain ``np.asarray`` does, and the hot path is unchanged."""
+    if recording():
+        import jax
+
+        with span("fused.wait"):
+            jax.block_until_ready(out)
+    with span("fused.fetch"):
+        host = [np.asarray(o) for o in out]
+    transfer["to_host"] += sum(h.nbytes for h in host)
+    return host
 
 
 def run_fused_bisection(pb, p_fix: np.ndarray, lo: np.ndarray, hi: np.ndarray,
@@ -1005,9 +1065,11 @@ def run_fused_bisection(pb, p_fix: np.ndarray, lo: np.ndarray, hi: np.ndarray,
         act = np.zeros(S, dtype=bool)
         act[:rows.size] = True
         _DISPATCHES[0] += 1
-        res = fn(pb.delta[sel], pb.s[sel], b, np.float64(0.0),
-                 pb.prefix[sel], pb.order[sel].astype(np.int64), p_fix[sel],
-                 lo[sel], hi[sel], act)
-        for name, val in zip(names, res):
-            out[name][rows] = np.asarray(val)[:rows.size]
+        with span("fused.launch"):
+            res = _call(fn, _TRANSFER, pb.delta[sel], pb.s[sel], b,
+                        np.float64(0.0), pb.prefix[sel],
+                        pb.order[sel].astype(np.int64), p_fix[sel], lo[sel],
+                        hi[sel], act)
+        for name, val in zip(names, _fetch(res, _TRANSFER)):
+            out[name][rows] = val[:rows.size]
     return out

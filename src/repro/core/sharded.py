@@ -52,15 +52,20 @@ import numpy as np
 
 from . import fused
 from .fused import chunk_rows
+from .spans import span
 
 __all__ = ["sharded_available", "device_count", "run_sharded",
            "run_sharded_bisection", "trace_count", "reset_trace_count",
-           "dispatch_count", "reset_dispatch_count", "output_devices"]
+           "dispatch_count", "reset_dispatch_count", "transfer_bytes",
+           "output_devices"]
 
 # traces / dispatches of the SPMD programs, mirroring fused.py's counters
 # (the shared bucket branches still count into fused._BUCKET_TRACES).
 _TRACES = [0]
 _DISPATCHES = [0]
+# bytes of every argument and output of the SPMD programs' calls, as
+# fused.transfer_bytes counts the fused engine's
+_TRANSFER = {"to_device": 0, "to_host": 0}
 # devices the outputs of the latest dispatch are laid out over
 _OUT_DEVICES = [0]
 
@@ -83,6 +88,14 @@ def dispatch_count() -> int:
 
 def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
+    _TRANSFER.update(to_device=0, to_host=0)
+
+
+def transfer_bytes() -> dict:
+    """``to_device`` and ``to_host`` bytes of the SPMD programs' calls
+    since :func:`reset_dispatch_count`, as :func:`fused.transfer_bytes`
+    counts the fused engine's: over :func:`dispatch_count`, per call."""
+    return dict(_TRANSFER)
 
 
 def output_devices() -> int:
@@ -209,7 +222,7 @@ def run_sharded(state, k: int, bi_mode: np.ndarray, stop: np.ndarray,
     band = fused.device_band()
     fused.run_loop(state, k, bi_mode, stop, lat_limit, record, S_local * D,
                    lambda T: _get_sharded_loop(n, p, k, T, S_local, band),
-                   band, _DISPATCHES)
+                   band, _DISPATCHES, _TRANSFER)
 
 
 def run_sharded_bisection(pb, p_fix: np.ndarray, lo: np.ndarray,
@@ -247,10 +260,12 @@ def run_sharded_bisection(pb, p_fix: np.ndarray, lo: np.ndarray,
         act = np.zeros(S, dtype=bool)
         act[:rows.size] = True
         _DISPATCHES[0] += 1
-        res = fn(pb.delta[sel], pb.s[sel], b, np.float64(0.0),
-                 pb.prefix[sel], pb.order[sel].astype(np.int64), p_fix[sel],
-                 lo[sel], hi[sel], act)
+        with span("fused.launch"):
+            res = fused._call(fn, _TRANSFER, pb.delta[sel], pb.s[sel], b,
+                              np.float64(0.0), pb.prefix[sel],
+                              pb.order[sel].astype(np.int64), p_fix[sel],
+                              lo[sel], hi[sel], act)
         _OUT_DEVICES[0] = len(res[0].sharding.device_set)
-        for name, val in zip(names, res):
-            out[name][rows] = np.asarray(val)[:rows.size]
+        for name, val in zip(names, fused._fetch(res, _TRANSFER)):
+            out[name][rows] = val[:rows.size]
     return out

@@ -57,6 +57,7 @@ from ..core.batched import (ProblemBatch, _as_problem_batch,
 from ..core.heuristics import split_trajectory, sp_bi_p
 from ..core.metrics import period as eval_period
 from ..core.metrics import single_processor_mapping
+from ..core.spans import span
 from .generators import gen_instance_batch
 
 N_STAGES_DEFAULT = (5, 10, 20, 40)
@@ -242,64 +243,68 @@ def _campaign_core(pb, workloads, platforms, pgrids, lgrids, n_bounds,
     points = {c: [[None] * n_bounds for _ in range(G)] for c in codes_p + ["H5", "H6"]}
     thr = {}
 
-    trajs = batched_trajectory_sets(codes_p, pb, backend=backend)
-    for c in ["H1", "H2", "H3"]:
-        thr[c] = [min(per for per, _ in trajs[c][g]) for g in range(G)]
-        for g in range(G):
-            for bi in range(n_bounds):
-                points[c][g][bi] = _result_from_trajectory(trajs[c][g], pgrids[g][bi])
+    with span("campaign.trajectories"):
+        trajs = batched_trajectory_sets(codes_p, pb, backend=backend)
+        for c in ["H1", "H2", "H3"]:
+            thr[c] = [min(per for per, _ in trajs[c][g]) for g in range(G)]
+            for g in range(G):
+                for bi in range(n_bounds):
+                    points[c][g][bi] = _result_from_trajectory(trajs[c][g], pgrids[g][bi])
     if include_h4:
-        thr["H4"] = [min(per for per, _ in trajs["H4"][g]) for g in range(G)]
-        # One lockstep binary search over every (instance, bound) problem that
-        # the trajectory proves feasible.
-        todo = [(g, bi) for g in range(G) for bi in range(n_bounds)
-                if _result_from_trajectory(trajs["H4"][g], pgrids[g][bi]) is not None]
-        if todo:
-            sub = pb.take([g for g, _ in todo])
-            bounds = [pgrids[g][bi] for g, bi in todo]
-            res4 = batched_sp_bi_p(sub, bounds, iters=h4_iters, backend=backend,
-                                   with_mappings=False,
-                                   groups=[g for g, _ in todo])
-            for (g, bi), r in zip(todo, res4):
-                if r.feasible:
-                    points["H4"][g][bi] = (r.period, r.latency)
+        with span("campaign.h4_bisection"):
+            thr["H4"] = [min(per for per, _ in trajs["H4"][g]) for g in range(G)]
+            # One lockstep binary search over every (instance, bound) problem
+            # that the trajectory proves feasible.
+            todo = [(g, bi) for g in range(G) for bi in range(n_bounds)
+                    if _result_from_trajectory(trajs["H4"][g], pgrids[g][bi]) is not None]
+            if todo:
+                sub = pb.take([g for g, _ in todo])
+                bounds = [pgrids[g][bi] for g, bi in todo]
+                res4 = batched_sp_bi_p(sub, bounds, iters=h4_iters, backend=backend,
+                                       with_mappings=False,
+                                       groups=[g for g, _ in todo])
+                for (g, bi), r in zip(todo, res4):
+                    if r.feasible:
+                        points["H4"][g][bi] = (r.period, r.latency)
 
-    # H5/H6 over the (instance x bound) grid.  The running latency of the
-    # splitting loop is monotone non-decreasing (new processors are never
-    # faster than enrolled ones, so dlat >= 0), hence every bound at or above
-    # the *unconstrained* run's final latency provably reproduces that run —
-    # one lockstep pass per instance covers the whole tail of its bound grid,
-    # and only the binding bounds run individually.
-    for c in ("H5", "H6"):
-        st_inf, _ = _fixed_latency_state(c, pb, np.full(G, np.inf), backend)
-        m_inf = st_inf.latency()
-        metr_inf = evaluate_state_rows(workloads, platforms, st_inf)
-        # safety margin: the loop's cur_lat+dlat feasibility probe can exceed
-        # the post-step state latency by a few ulps
-        cut = m_inf + 1e-9 * np.maximum(1.0, np.abs(m_inf))
-        con = [(g, bi) for g in range(G) for bi in range(n_bounds)
-               if lgrids[g][bi] < cut[g]]
-        metr_con = {}
-        if con:
-            sub = pb.take([g for g, _ in con])
-            bnds = np.array([lgrids[g][bi] for g, bi in con])
-            st_c, failed_c = _fixed_latency_state(c, sub, bnds, backend)
-            mc = evaluate_state_rows([workloads[g] for g, _ in con],
-                                     [platforms[g] for g, _ in con],
-                                     st_c, skip=failed_c)
-            for row, gb in enumerate(con):
-                metr_con[gb] = None if failed_c[row] else (mc[row, 0], mc[row, 1])
-        # Replicate the solve() layer: candidate metrics come from
-        # metrics.evaluate on the mapping, feasibility from meets_bound.
-        for g in range(G):
-            for bi in range(n_bounds):
-                v = metr_con.get((g, bi), (metr_inf[g, 0], metr_inf[g, 1]))
-                if v is None:
-                    continue
-                per, lat = float(v[0]), float(v[1])
-                if (math.isfinite(per) and math.isfinite(lat)
-                        and lat <= float(lgrids[g][bi]) + 1e-12):
-                    points[c][g][bi] = (per, lat)
+    with span("campaign.h5h6"):
+        # H5/H6 over the (instance x bound) grid.  The running latency of
+        # the splitting loop is monotone non-decreasing (new processors are
+        # never faster than enrolled ones, so dlat >= 0), hence every bound
+        # at or above the *unconstrained* run's final latency provably
+        # reproduces that run — one lockstep pass per instance covers the
+        # whole tail of its bound grid, and only the binding bounds run
+        # individually.
+        for c in ("H5", "H6"):
+            st_inf, _ = _fixed_latency_state(c, pb, np.full(G, np.inf), backend)
+            m_inf = st_inf.latency()
+            metr_inf = evaluate_state_rows(workloads, platforms, st_inf)
+            # safety margin: the loop's cur_lat+dlat feasibility probe can
+            # exceed the post-step state latency by a few ulps
+            cut = m_inf + 1e-9 * np.maximum(1.0, np.abs(m_inf))
+            con = [(g, bi) for g in range(G) for bi in range(n_bounds)
+                   if lgrids[g][bi] < cut[g]]
+            metr_con = {}
+            if con:
+                sub = pb.take([g for g, _ in con])
+                bnds = np.array([lgrids[g][bi] for g, bi in con])
+                st_c, failed_c = _fixed_latency_state(c, sub, bnds, backend)
+                mc = evaluate_state_rows([workloads[g] for g, _ in con],
+                                         [platforms[g] for g, _ in con],
+                                         st_c, skip=failed_c)
+                for row, gb in enumerate(con):
+                    metr_con[gb] = None if failed_c[row] else (mc[row, 0], mc[row, 1])
+            # Replicate the solve() layer: candidate metrics come from
+            # metrics.evaluate on the mapping, feasibility from meets_bound.
+            for g in range(G):
+                for bi in range(n_bounds):
+                    v = metr_con.get((g, bi), (metr_inf[g, 0], metr_inf[g, 1]))
+                    if v is None:
+                        continue
+                    per, lat = float(v[0]), float(v[1])
+                    if (math.isfinite(per) and math.isfinite(lat)
+                            and lat <= float(lgrids[g][bi]) + 1e-12):
+                        points[c][g][bi] = (per, lat)
     return points, thr
 
 
@@ -327,40 +332,46 @@ def run_campaign(
     exps = list(exps)
     period_fracs = np.geomspace(0.04, 1.0, n_bounds)     # x single-processor period
     latency_mults = np.linspace(1.0, 3.0, n_bounds)      # x optimal latency
-    seeds = [seed0 + k for k in range(n_pairs)]
-    batches = [gen_instance_batch(exp, n, p, seeds) for exp in exps]
-    workloads = [wl for b in batches for wl in b.workloads]
-    platforms = [pf for b in batches for pf in b.platforms]
-    pb = ProblemBatch.concat(batches)
-    his = [eval_period(wl, pf, single_processor_mapping(wl, pf.fastest()))
-           for wl, pf in zip(workloads, platforms)]
-    lopts = [optimal_latency(wl, pf) for wl, pf in zip(workloads, platforms)]
-    pgrids = [hi * period_fracs for hi in his]
-    lgrids = [l_opt * latency_mults for l_opt in lopts]
+    with span("campaign.instances"):
+        seeds = [seed0 + k for k in range(n_pairs)]
+        batches = [gen_instance_batch(exp, n, p, seeds) for exp in exps]
+        workloads = [wl for b in batches for wl in b.workloads]
+        platforms = [pf for b in batches for pf in b.platforms]
+        pb = ProblemBatch.concat(batches)
+        his = [eval_period(wl, pf, single_processor_mapping(wl, pf.fastest()))
+               for wl, pf in zip(workloads, platforms)]
+        lopts = [optimal_latency(wl, pf)
+                 for wl, pf in zip(workloads, platforms)]
+        pgrids = [hi * period_fracs for hi in his]
+        lgrids = [l_opt * latency_mults for l_opt in lopts]
 
     points, thr_vals = _campaign_core(pb, workloads, platforms, pgrids, lgrids,
                                       n_bounds, h4_iters, include_h4, backend)
-    thr_vals = dict(thr_vals)
-    for c in ("H5", "H6"):
-        thr_vals[c] = lopts
-
-    out = {}
-    codes = ["H1", "H2", "H3"] + (["H4"] if include_h4 else []) + ["H5", "H6"]
-    for ei, exp in enumerate(exps):
-        lo = ei * n_pairs
-        curves = {}
-        for c in codes:
-            cols = [[points[c][g][bi] for g in range(lo, lo + n_pairs)
-                     if points[c][g][bi] is not None] for bi in range(n_bounds)]
-            mean_per = np.array([np.mean([a for a, _ in col]) if col else np.nan
-                                 for col in cols])
-            mean_lat = np.array([np.mean([b for _, b in col]) if col else np.nan
-                                 for col in cols])
-            frac = np.array([len(col) / n_pairs for col in cols])
-            curves[c] = (mean_per, mean_lat, frac)
-        thr = {c: (float(np.mean(thr_vals[c][lo:lo + n_pairs])),
-                   float(np.max(thr_vals[c][lo:lo + n_pairs]))) for c in codes}
-        out[exp] = ExperimentResult(exp, n, p, n_pairs, period_fracs, curves, thr)
+    with span("campaign.assemble"):
+        thr_vals = dict(thr_vals)
+        for c in ("H5", "H6"):
+            thr_vals[c] = lopts
+        out = {}
+        codes = (["H1", "H2", "H3"] + (["H4"] if include_h4 else [])
+                 + ["H5", "H6"])
+        for ei, exp in enumerate(exps):
+            lo = ei * n_pairs
+            curves = {}
+            for c in codes:
+                cols = [[points[c][g][bi] for g in range(lo, lo + n_pairs)
+                         if points[c][g][bi] is not None]
+                        for bi in range(n_bounds)]
+                mean_per = np.array([np.mean([a for a, _ in col])
+                                     if col else np.nan for col in cols])
+                mean_lat = np.array([np.mean([b for _, b in col])
+                                     if col else np.nan for col in cols])
+                frac = np.array([len(col) / n_pairs for col in cols])
+                curves[c] = (mean_per, mean_lat, frac)
+            thr = {c: (float(np.mean(thr_vals[c][lo:lo + n_pairs])),
+                       float(np.max(thr_vals[c][lo:lo + n_pairs])))
+                   for c in codes}
+            out[exp] = ExperimentResult(exp, n, p, n_pairs, period_fracs,
+                                        curves, thr)
     return out
 
 

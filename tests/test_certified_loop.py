@@ -7,7 +7,8 @@ float64, and every float is replayed on the host with the numpy engine's
 own update.  These tests force the TPU band on the CPU and hold the result
 to the numpy engine exactly — also when the device's inputs are perturbed
 far beyond the TPU's float64 error, and when the band is so wide that the
-host decides nearly every step.
+host decides nearly every step.  The ``band`` fixture is in
+``conftest.py``.
 """
 
 import numpy as np
@@ -22,16 +23,6 @@ from repro.sim import EXPERIMENTS, gen_instance_batch
 from repro.sim.experiments import run_campaign
 
 SEEDS = range(7100, 7106)
-
-
-@pytest.fixture
-def band(monkeypatch):
-    """Force the certified loop at a given band (default: the TPU's)."""
-    def use(value=fused.TPU_BAND):
-        monkeypatch.setattr(fused, "device_band", lambda: value)
-        fused.reset_dispatch_count()
-    use()
-    return use
 
 
 def _campaigns_equal(a, b) -> bool:
