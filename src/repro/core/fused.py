@@ -36,8 +36,8 @@ Design differences from the numpy lockstep loop (same *choices*, fixed shape):
     of a campaign — trajectories, H4 bisection probes on shrinking subsets,
     H5/H6 bound-grid runs — reuses one trace per arity.  The carried SoA
     state buffers (items array, item counts, latency sums, split counts) are
-    donated to the jitted program, so XLA reuses their device buffers for
-    the outputs instead of allocating fresh ones per call.
+    donated to the jitted program where it returns them, so XLA reuses their
+    device buffers for the outputs instead of allocating fresh ones per call.
 
 Equivalence contract: split trajectories — the accepted splits AND their
 (period, latency) floats — are identical to the numpy engine on all tested
@@ -57,8 +57,10 @@ break differently.  There the loop runs CERTIFIED (:func:`device_band`,
 by more than the error band, parks the rows whose step is not, and the host
 replays the device's decisions and makes the parked ones in float64 with the
 numpy engine's own code (:func:`run_loop`) — same trajectories and floats,
-at the price of one more dispatch round per parked step.  The H4 bisection
-then probes through this loop from the host (``batched.batched_sp_bi_p``).
+at the price of one more dispatch round per parked step.  The certified
+program returns only those decisions, one packed int32 record a dispatch.
+The H4 bisection then probes through this loop from the host
+(``batched.batched_sp_bi_p``).
 
 Cold starts amortize across processes through JAX's persistent compilation
 cache (:func:`enable_persistent_cache` — benchmarks enable it by default).
@@ -84,6 +86,7 @@ __all__ = ["fused_available", "run_fused", "run_fused_bisection",
            "trace_count", "reset_trace_count", "traced_shapes",
            "decision_counts", "device_band", "run_loop",
            "dispatch_count", "reset_dispatch_count", "transfer_bytes",
+           "fetch_copies",
            "bucket_trace_count", "reset_bucket_trace_count",
            "bucket_sizes", "bucket_index", "trace_budget",
            "enable_persistent_cache"]
@@ -106,8 +109,9 @@ _DISPATCHES = [0]
 # the device decided, and parked row-steps the host re-decided in float64
 _DECISIONS = {"device": 0, "host": 0}
 # bytes handed to this engine's jitted programs (every argument of every
-# dispatch) and copied back (every output) since the last dispatch-count reset
-_TRANSFER = {"to_device": 0, "to_host": 0}
+# dispatch) and copied back (every output), and the device-to-host copies,
+# since the last dispatch-count reset
+_TRANSFER = {"to_device": 0, "to_host": 0, "copies": 0}
 
 # Relative error bound, against each row's magnitude scale, that certified
 # loops allow the device arithmetic.  TPU float64 is emulated with float32
@@ -121,6 +125,11 @@ TPU_BAND = 2.0 ** -28
 # first stage, last stage and processor, part count, processors consumed
 DEC_FIELDS = ("widx", "d0", "d1", "d2", "e0", "e1", "e2", "u0", "u1", "u2",
               "nparts", "consumed")
+# fields of the certified loop's packed record, int32 of shape
+# (T + 1, len(REC_FIELDS), S): at t < T iteration t's decision and whether it
+# was accepted; at T each row's parked flag (field 0) and the loop's
+# iteration count (field 1)
+REC_FIELDS = DEC_FIELDS + ("accept",)
 
 # lane budget per jitted call: rows_per_chunk * candidate_lanes is held under
 # this so the 3-way pair grid of large n stays cache-/memory-sized.  Sized
@@ -179,7 +188,7 @@ def dispatch_count() -> int:
 def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
     _DECISIONS.update(device=0, host=0)
-    _TRANSFER.update(to_device=0, to_host=0)
+    _TRANSFER.update(to_device=0, to_host=0, copies=0)
 
 
 def transfer_bytes() -> dict:
@@ -189,7 +198,15 @@ def transfer_bytes() -> dict:
     ``to_host`` those of every output, so that bytes over
     :func:`dispatch_count` are per call.  The sharded engine counts its own
     (``sharded.transfer_bytes``)."""
-    return dict(_TRANSFER)
+    return {k: _TRANSFER[k] for k in ("to_device", "to_host")}
+
+
+def fetch_copies() -> int:
+    """Device-to-host copies since :func:`reset_dispatch_count`, one per
+    output fetched (the certified loop's one packed record, the other
+    programs' every output); the sharded engine counts its own
+    (``sharded.fetch_copies``)."""
+    return _TRANSFER["copies"]
 
 
 def decision_counts() -> dict:
@@ -312,13 +329,16 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int,
         loop(delta, s, b, zero, prefix, order, bi_mode, stop, lat_limit,
              active0, arr0, m0, nx0, lat0, sp0, scale, sbits)
           -> (arr, m, next_idx, lat_sum, splits, parked,
-              per_rec, lat_rec, acc_rec, dec_rec, t)
+              per_rec, lat_rec, acc_rec, dec_rec, t)      (band == 0)
+          -> rec                                          (band > 0)
 
     with ``arr`` (S, n, 5) in the ``_BatchState`` field layout, the records
     (T, S) per lockstep iteration and ``dec_rec`` (T, S, 12) the decision of
-    each iteration (:data:`DEC_FIELDS`).  Callers jit the loop with the SoA
-    state arguments donated (:func:`_get_loop`) or inline it into a larger
-    traced program (:func:`_get_bisect`).  Candidate scoring runs through a
+    each iteration (:data:`DEC_FIELDS`).  The certified loop returns its
+    decisions alone, packed into one int32 array ``rec`` (:data:`REC_FIELDS`):
+    the host replays the state itself, so it needs nothing else.  Callers
+    jit the loop (:func:`_get_loop`) or inline it into a larger traced
+    program (:func:`_get_bisect`).  Candidate scoring runs through a
     ``lax.switch`` over the geometric span buckets of :func:`bucket_sizes`.
 
     ``band > 0`` builds the CERTIFIED loop for devices whose float64 is not
@@ -585,27 +605,26 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int,
     def loop(delta, s, b, zero, prefix, order, bi_mode, stop, lat_limit,
              active0, arr0, m0, nx0, lat0, sp0, scale, sbits):
         tail = delta[:, n] / b
-        per_rec = jnp.zeros((T, S))
-        lat_rec = jnp.zeros((T, S))
-        acc_rec = jnp.zeros((T, S), dtype=bool)
-        dec_rec = jnp.zeros((T, S, len(DEC_FIELDS)), dtype=jnp.int64)
         if band:
             gam = band * scale
             gam_lat = band * (scale + jnp.where(jnp.isfinite(lat_limit),
                                                 jnp.abs(lat_limit), 0.0))
             gam_stop = band * (scale + jnp.where(jnp.isfinite(stop),
                                                  jnp.abs(stop), 0.0))
+            recs = (jnp.zeros((T + 1, len(REC_FIELDS), S), dtype=jnp.int32),)
         else:
             gam = gam_lat = gam_stop = jnp.zeros(S)
+            recs = (jnp.zeros((T, S)), jnp.zeros((T, S)),
+                    jnp.zeros((T, S), dtype=bool),
+                    jnp.zeros((T, S, len(DEC_FIELDS)), dtype=jnp.int64))
 
         def cond(carry):
             t, active = carry[0], carry[5]
             return (t < T) & active.any()
 
         def body(carry):
-            (t, arr, m, next_idx, lat_sum, active,
-             per_rec, lat_rec, acc_rec) = carry[:9]
-            splits, parked, dec_rec = carry[9:]
+            (t, arr, m, next_idx, lat_sum, active, splits, parked,
+             recs) = carry
             cyc = arr[:, :, 3]
             per = cyc.max(axis=1)
             live = active & (per > stop + _EPS)
@@ -732,22 +751,34 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int,
             lat_sum = jnp.where(accept, new_lat, lat_sum)
             splits = splits + accept.astype(jnp.int64)
 
-            per_rec = per_rec.at[t].set(arr[:, :, 3].max(axis=1))
-            lat_rec = lat_rec.at[t].set(lat_sum + tail)
-            acc_rec = acc_rec.at[t].set(accept)
-            dec_rec = dec_rec.at[t].set(jnp.concatenate(
-                [widx[:, None], pdc, pec, puc, nparts[:, None],
-                 consumed[:, None]], axis=1).astype(jnp.int64))
-            return (t + 1, arr, m, next_idx, lat_sum, accept,
-                    per_rec, lat_rec, acc_rec, splits, parked | unc, dec_rec)
+            if band:
+                # the decision as int32 rows, rows of the batch along lanes
+                (rec,) = recs
+                fields = [widx[None], pdc.T, pec.T, puc.T, nparts[None],
+                          consumed[None], accept[None]]
+                recs = (rec.at[t].set(jnp.concatenate(
+                    [f.astype(jnp.int32) for f in fields], axis=0)),)
+            else:
+                per_rec, lat_rec, acc_rec, dec_rec = recs
+                recs = (per_rec.at[t].set(arr[:, :, 3].max(axis=1)),
+                        lat_rec.at[t].set(lat_sum + tail),
+                        acc_rec.at[t].set(accept),
+                        dec_rec.at[t].set(jnp.concatenate(
+                            [widx[:, None], pdc, pec, puc, nparts[:, None],
+                             consumed[:, None]], axis=1).astype(jnp.int64)))
+            return (t + 1, arr, m, next_idx, lat_sum, accept, splits,
+                    parked | unc, recs)
 
-        init = (jnp.int64(0), arr0, m0, nx0, lat0, active0,
-                per_rec, lat_rec, acc_rec, sp0, jnp.zeros(S, dtype=bool),
-                dec_rec)
-        (t, arr, m, next_idx, lat_sum, active, per_rec, lat_rec, acc_rec,
-         splits, parked, dec_rec) = lax.while_loop(cond, body, init)
-        return (arr, m, next_idx, lat_sum, splits, parked,
-                per_rec, lat_rec, acc_rec, dec_rec, t)
+        init = (jnp.int64(0), arr0, m0, nx0, lat0, active0, sp0,
+                jnp.zeros(S, dtype=bool), recs)
+        (t, arr, m, next_idx, lat_sum, _active, splits, parked,
+         recs) = lax.while_loop(cond, body, init)
+        if band:
+            trailer = jnp.zeros((len(REC_FIELDS), S), dtype=jnp.int32)
+            trailer = (trailer.at[0].set(parked.astype(jnp.int32))
+                       .at[1].set(t.astype(jnp.int32)))
+            return recs[0].at[T].set(trailer)
+        return (arr, m, next_idx, lat_sum, splits, parked, *recs, t)
 
     return init_state, loop
 
@@ -756,13 +787,20 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int,
 def _get_loop(n: int, p: int, k: int, T: int, S: int,
               band: float = 0.0) -> Callable:
     """The jitted fused loop for static shape (n, p, k), cached per shape.
-    The five carried SoA state buffers (arr, m, next_idx, lat_sum, splits)
-    are donated: XLA reuses their device buffers for the outputs."""
+    Where it returns the state (``band == 0``), the five carried SoA state
+    buffers (arr, m, next_idx, lat_sum, splits) are donated: XLA reuses
+    their device buffers for the outputs."""
     import jax
 
     _init_state, loop = _build_loop(n, p, k, T, S, band)
     return jax.jit(_named(f"fused_loop_k{k}", n, p, loop),
-                   donate_argnums=(10, 11, 12, 13, 14))
+                   donate_argnums=_donated(band))
+
+
+def _donated(band: float) -> tuple:
+    """Arguments of the loop whose buffers its outputs reuse: the SoA state
+    where the loop returns the state, none where it returns the record."""
+    return () if band else (10, 11, 12, 13, 14)
 
 
 def _named(name: str, n: int, p: int, fn: Callable) -> Callable:
@@ -880,16 +918,19 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
              transfer: dict) -> None:
     """Host driver shared by the fused and sharded engines: run the traced
     loop ``get_program(T)`` over ``state``'s active rows in chunks of ``S``,
-    counting each dispatch in ``dispatches[0]`` and the bytes of its
-    arguments and outputs in ``transfer`` (the engine's own counters).
+    counting each dispatch in ``dispatches[0]``, and the bytes of its
+    arguments and outputs and its copies back in ``transfer`` (the engine's
+    own counters).
 
     ``band == 0``: one dispatch per chunk; the device's final state and
     per-iteration records are written back as they are.  ``band > 0``
-    (certified loop): the device's decisions are replayed on the host in
-    float64 with the numpy engine's own ``_apply_splits``, so every float is
-    the numpy engine's; rows the device parked take one float64 step of the
-    numpy loop and go back to the device, until no row is active.  Either
-    way ``record`` sees the numpy engine's lockstep sequence.
+    (certified loop): the program returns its decisions alone, one packed
+    int32 record (:data:`REC_FIELDS`) fetched in one copy; they are replayed
+    on the host in float64 with the numpy engine's own ``_apply_splits``, so
+    every float is the numpy engine's; rows the device parked take one
+    float64 step of the numpy loop and go back to the device, until no row
+    is active.  Either way ``record`` sees the numpy engine's lockstep
+    sequence.
 
     Each dispatch runs under the spans ``fused.launch`` and ``fused.fetch``
     (with ``fused.wait`` split off while a profiler records), the host's
@@ -936,7 +977,7 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
                 act[:r] = True
                 dispatches[0] += 1
                 # the SoA state slices are fresh fancy-index copies, safe to
-                # donate
+                # donate (the uncertified program does)
                 out = _call(fn, transfer, pb.delta[sel], pb.s[sel], b,
                             np.float64(0.0), pb.prefix[sel],
                             pb.order[sel].astype(np.int64), bi_mode[sel],
@@ -944,37 +985,40 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
                             state.m[sel], state.next_idx[sel],
                             state.lat_sum[sel], state.splits[sel],
                             scale[sel], sbits[sel])
-            (arr, m, next_idx, lat_sum, splits, park, per_rec, lat_rec,
-             acc_rec, dec_rec, t_used) = _fetch(out, transfer)
-            with span("fused.replay"):
-                t_used = int(t_used.max())
-                state.active[rows] = False
-                if not band:
+            if not band:
+                (arr, m, next_idx, lat_sum, splits, _park, per_rec, lat_rec,
+                 acc_rec, _dec, t_used) = _fetch(out, transfer)
+                with span("fused.replay"):
+                    state.active[rows] = False
                     state.arr[rows] = arr[:r]
                     state.m[rows] = m[:r]
                     state.next_idx[rows] = next_idx[:r]
                     state.lat_sum[rows] = lat_sum[:r]
                     state.splits[rows] = splits[:r]
-                    for t in range(t_used):
+                    for t in range(int(t_used.max())):
                         a = acc_rec[t, :r]
                         if a.any():
                             steps.append((rows[a], np.full(a.sum(), t),
                                           per_rec[t, :r][a],
                                           lat_rec[t, :r][a]))
-                    continue
-                for t in range(t_used):
-                    a = acc_rec[t, :r]
+                continue
+            (rec,) = _fetch((out,), transfer)
+            with span("fused.replay"):
+                state.active[rows] = False
+                rec = rec[:, :, :r].transpose(0, 2, 1)   # (T + 1, r, fields)
+                for t in range(int(rec[T, :, 1].max())):
+                    a = rec[t, :, -1] != 0
                     if not a.any():
                         continue
                     acc = rows[a]
-                    dec = dec_rec[t, :r][a]
+                    dec = rec[t, a, :-1].astype(np.int64)
                     _DECISIONS["device"] += acc.size
                     _apply_splits(state, acc, dec[:, 0], dec[:, 1:4],
                                   dec[:, 4:7], dec[:, 7:10], dec[:, 10],
                                   dec[:, 11])
                     note(acc, state.arr[acc, :, 3].max(axis=1),
                          state.lat_sum[acc] + state.tail[acc])
-                parked.append(rows[park[:r]])
+                parked.append(rows[rec[T, :, 0] != 0])
         todo = np.concatenate(parked) if parked else todo[:0]
         if todo.size:
             # the decisions the device could not certify, made in float64
@@ -1011,10 +1055,11 @@ def _call(fn: Callable, transfer: dict, *args):
 
 def _fetch(out, transfer: dict) -> list:
     """A program call's outputs on the host (``fused.fetch``), their
-    ``nbytes`` counted into ``transfer["to_host"]``.  While a profiler
-    records (:func:`repro.core.spans.recording`) the wait for the device is
-    split off first as ``fused.wait``; otherwise the first conversion waits,
-    as a plain ``np.asarray`` does, and the hot path is unchanged."""
+    ``nbytes`` counted into ``transfer["to_host"]`` and their number into
+    ``transfer["copies"]``.  While a profiler records
+    (:func:`repro.core.spans.recording`) the wait for the device is split off
+    first as ``fused.wait``; otherwise the first conversion waits, as a plain
+    ``np.asarray`` does, and the hot path is unchanged."""
     if recording():
         import jax
 
@@ -1023,6 +1068,7 @@ def _fetch(out, transfer: dict) -> list:
     with span("fused.fetch"):
         host = [np.asarray(o) for o in out]
     transfer["to_host"] += sum(h.nbytes for h in host)
+    transfer["copies"] += len(host)
     return host
 
 

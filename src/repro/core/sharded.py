@@ -57,15 +57,16 @@ from .spans import span
 __all__ = ["sharded_available", "device_count", "run_sharded",
            "run_sharded_bisection", "trace_count", "reset_trace_count",
            "dispatch_count", "reset_dispatch_count", "transfer_bytes",
-           "output_devices"]
+           "fetch_copies", "output_devices"]
 
 # traces / dispatches of the SPMD programs, mirroring fused.py's counters
 # (the shared bucket branches still count into fused._BUCKET_TRACES).
 _TRACES = [0]
 _DISPATCHES = [0]
-# bytes of every argument and output of the SPMD programs' calls, as
-# fused.transfer_bytes counts the fused engine's
-_TRANSFER = {"to_device": 0, "to_host": 0}
+# bytes of every argument and output of the SPMD programs' calls and their
+# copies back, as fused.transfer_bytes and fused.fetch_copies count the fused
+# engine's
+_TRANSFER = {"to_device": 0, "to_host": 0, "copies": 0}
 # devices the outputs of the latest dispatch are laid out over
 _OUT_DEVICES = [0]
 
@@ -88,14 +89,21 @@ def dispatch_count() -> int:
 
 def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
-    _TRANSFER.update(to_device=0, to_host=0)
+    _TRANSFER.update(to_device=0, to_host=0, copies=0)
 
 
 def transfer_bytes() -> dict:
     """``to_device`` and ``to_host`` bytes of the SPMD programs' calls
     since :func:`reset_dispatch_count`, as :func:`fused.transfer_bytes`
     counts the fused engine's: over :func:`dispatch_count`, per call."""
-    return dict(_TRANSFER)
+    return {k: _TRANSFER[k] for k in ("to_device", "to_host")}
+
+
+def fetch_copies() -> int:
+    """Device-to-host copies of the SPMD programs' outputs since
+    :func:`reset_dispatch_count`, as :func:`fused.fetch_copies` counts the
+    fused engine's."""
+    return _TRANSFER["copies"]
 
 
 def output_devices() -> int:
@@ -128,27 +136,31 @@ def _mesh():
     return Mesh(np.array(jax.devices()), ("i",))
 
 
-def _shard_wrap(fn: Callable, n_state_out: int, n_rec_out: int,
-                mesh) -> Callable:
-    """Wrap an unjitted per-shard program in ``shard_map`` over the row axis.
+def _shard_wrap(fn: Callable, band: float, mesh) -> Callable:
+    """Wrap ``fused._build_loop``'s unjitted loop (built at ``band``) in
+    ``shard_map`` over the row axis.
 
-    ``fn(*args) -> (*state..., *records..., t)`` where the state outputs are
-    row-leading, the records are (T, S_local, ...), and ``t`` is a per-shard
-    scalar.  Scalar inputs (0-d) are replicated; every other input is
-    sharded along its leading axis.  The per-shard iteration count comes
-    back broadcast per-row so the host can take the global max.
+    Uncertified (``band == 0``) it returns ``(*state..., *records..., t)``:
+    six row-leading state outputs, four (T, S_local, ...) records and a
+    per-shard scalar ``t``, which comes back broadcast per row so the host
+    can take the global max.  Certified it returns the packed record
+    (T + 1, fields, S_local), whose trailer already holds ``t`` per row.
+    Scalar inputs (0-d) are replicated; every other input is sharded along
+    its leading axis.
     """
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     row = P("i")
-    rec = P(None, "i")
+    out_specs = (P(None, None, "i") if band
+                 else (row,) * 6 + (P(None, "i"),) * 4 + (row,))
 
     def local(*args):
         out = fn(*args)
-        t = out[-1]
-        t_rows = jnp.full((out[0].shape[0],), t, dtype=jnp.int64)
+        if band:
+            return out
+        t_rows = jnp.full((out[0].shape[0],), out[-1], dtype=jnp.int64)
         return (*out[:-1], t_rows)
 
     def specs_for(args):
@@ -157,8 +169,7 @@ def _shard_wrap(fn: Callable, n_state_out: int, n_rec_out: int,
     def wrapped(*args):
         _TRACES[0] += 1  # Python-executes only while tracing
         body = jax.shard_map(local, mesh=mesh, in_specs=specs_for(args),
-                             out_specs=(row,) * n_state_out
-                             + (rec,) * n_rec_out + (row,), check_vma=False)
+                             out_specs=out_specs, check_vma=False)
         return body(*args)
 
     return wrapped
@@ -169,16 +180,17 @@ def _get_sharded_loop(n: int, p: int, k: int, T: int, S_local: int,
                       band: float = 0.0) -> Callable:
     """The jitted SPMD fused loop for static shape (n, p, k): per-shard rows
     ``S_local``, global rows ``S_local * device_count()``.  SoA state buffers
-    donated, exactly like ``fused._get_loop``."""
+    donated where the loop returns the state, exactly like
+    ``fused._get_loop``."""
     import jax
 
     _init_state, loop = fused._build_loop(n, p, k, T, S_local, band)
-    wrapped = _shard_wrap(loop, n_state_out=6, n_rec_out=4, mesh=_mesh())
-    jitted = jax.jit(wrapped, donate_argnums=(10, 11, 12, 13, 14))
+    jitted = jax.jit(_shard_wrap(loop, band, _mesh()),
+                     donate_argnums=fused._donated(band))
 
     def run(*args):
         out = jitted(*args)
-        _OUT_DEVICES[0] = len(out[0].sharding.device_set)
+        _OUT_DEVICES[0] = len((out if band else out[0]).sharding.device_set)
         return out
 
     return run

@@ -135,3 +135,51 @@ def test_certified_sharded_matches_numpy(band):
                 == batched_trajectories(code, batch, backend="numpy")), code
     assert sharded.dispatch_count() > 0
     assert sharded.output_devices() == sharded.device_count()
+
+
+@pytest.mark.parametrize("engine", ["fused", "sharded"])
+@pytest.mark.parametrize("k", [1, 2])
+def test_certified_program_returns_one_packed_int32_record(band, monkeypatch,
+                                                           engine, k):
+    """Each certified call returns exactly one int32 array of (T + 1) x 13 x
+    S: the decisions the device accepted, each as the uncertified program
+    makes it from the same arguments, then each row's parked flag and the
+    iteration count."""
+    import jax
+
+    mod, name = ((fused, "_get_loop") if engine == "fused"
+                 else (sharded, "_get_sharded_loop"))
+    get = getattr(mod, name)
+    calls = []
+
+    def spy(*shape):
+        fn = get(*shape)
+
+        def run(*args):
+            out = fn(*args)
+            calls.append((shape, args, out))
+            return out
+        return run
+
+    monkeypatch.setattr(mod, name, spy)
+    n, p = 12, 10
+    batch = gen_instance_batch("E1", n, p, SEEDS)
+    batched_trajectories("H1" if k == 1 else "H3", batch, backend=engine)
+    assert calls
+    F = len(fused.REC_FIELDS)
+    for (n_, p_, k_, T, S_local, b), args, rec in calls:
+        assert (n_, p_, k_, b) == (n, p, k, fused.TPU_BAND)
+        S = args[0].shape[0]
+        assert isinstance(rec, jax.Array)
+        assert rec.dtype == np.int32 and rec.shape == (T + 1, F, S)
+        rec = np.asarray(rec)
+        park, t = rec[T, 0], rec[T, 1]
+        assert set(np.unique(park)) <= {0, 1} and (rec[T, 2:] == 0).all()
+        assert (t == t.max()).all() if engine == "fused" else t.max() <= T
+        out = get(n_, p_, k_, T, S_local, 0.0)(*args)
+        acc_rec, dec_rec = np.asarray(out[8]), np.asarray(out[9])
+        acc = rec[:T, -1].astype(bool)                       # (T, S)
+        assert not acc[t.max():].any()
+        assert (acc <= acc_rec).all()
+        assert np.array_equal(rec[:T, :-1].transpose(0, 2, 1)[acc],
+                              dec_rec[acc])

@@ -71,12 +71,13 @@ def test_spans_of_a_fused_campaign(band, monkeypatch, certified):
     assert 0 < out["campaign_driver_s"] < out["window_s"]
 
 
-def _loop_bytes(n, p, S, T, t_rows=1):
+def _loop_bytes(n, p, S, T, t_rows=1, certified=False):
     """Argument and output bytes of one ``fused_loop`` call, from the
     program's contract (``fused._build_loop``): float64 and int64 are 8
-    bytes, bool 1.  The sharded program returns ``t`` per row
-    (``t_rows=S``)."""
-    f8, b1 = 8, 1
+    bytes, int32 4, bool 1.  The sharded program returns ``t`` per row
+    (``t_rows=S``).  The certified program returns one int32 record of
+    (T + 1) x 13 x S."""
+    f8, i4, b1 = 8, 4, 1
     args = (2 * S * (n + 1) * f8          # delta, prefix
             + 3 * S * p * f8              # s, order, sbits
             + 2 * f8                      # b, zero
@@ -88,6 +89,8 @@ def _loop_bytes(n, p, S, T, t_rows=1):
             + S * b1                      # parked
             + 2 * T * S * f8 + T * S * b1  # per_rec, lat_rec, acc_rec
             + T * S * 12 * f8 + t_rows * f8)  # dec_rec, t
+    if certified:
+        outs = (T + 1) * len(fused.REC_FIELDS) * S * i4
     return args, outs
 
 
@@ -101,9 +104,16 @@ def _bisect_bytes(n, p, S):
 
 
 @pytest.mark.parametrize("engine", ["fused", "sharded"])
-@pytest.mark.parametrize("code", ["H1", "H3", "bisection"])
-def test_transfer_bytes_match_the_shapes(engine, code):
-    """Each engine counts its own calls, beside its own dispatch counter."""
+@pytest.mark.parametrize("code,certified", [
+    ("H1", False), ("H3", False), ("bisection", False),
+    ("H1", True), ("H3", True)],
+    ids=["H1", "H3", "bisection", "H1-certified", "H3-certified"])
+def test_transfer_bytes_match_the_shapes(band, engine, code, certified):
+    """Each engine counts its own calls, beside its own dispatch counter:
+    eleven copies a call of the uncertified loop and of the bisection, one
+    of the certified loop's packed record (which calls again for the rows
+    it parks)."""
+    band(fused.TPU_BAND if certified else 0.0)
     n, p, B = 8, 6, 5
     batch = gen_instance_batch("E2", n, p, range(B))
     T = min(n - 1, p - 1)
@@ -122,14 +132,20 @@ def test_transfer_bytes_match_the_shapes(engine, code):
         batched_trajectories(code, batch, backend=engine)
         k = batched._TRAJ_CONFIG[code][1]
         S = fused.chunk_rows(n, k) * D
-        want = _loop_bytes(n, p, S, T, S if engine == "sharded" else 1)
-    assert mine.dispatch_count() == 1
+        want = _loop_bytes(n, p, S, T, S if engine == "sharded" else 1,
+                           certified)
+    calls = mine.dispatch_count()
+    assert calls >= 1 if certified else calls == 1
     got = mine.transfer_bytes()
-    assert (got["to_device"], got["to_host"]) == want
+    assert (got["to_device"], got["to_host"]) == (calls * want[0],
+                                                  calls * want[1])
+    assert mine.fetch_copies() == calls * (1 if certified else 11)
     assert other.dispatch_count() == 0
     assert other.transfer_bytes() == {"to_device": 0, "to_host": 0}
+    assert other.fetch_copies() == 0
     mine.reset_dispatch_count()
     assert mine.transfer_bytes() == {"to_device": 0, "to_host": 0}
+    assert mine.fetch_copies() == 0
 
 
 def test_no_explicit_wait_without_a_profiler(monkeypatch):
@@ -147,3 +163,23 @@ def test_no_explicit_wait_without_a_profiler(monkeypatch):
     assert fused.dispatch_count() > 0 and waits == []
     out = _traced(lambda: batched_trajectories("H1", batch, backend="fused"))
     assert len(waits) == out["spans"]["fused.wait"][1] > 0
+
+
+def test_copies_reader(monkeypatch):
+    """``fetch_copies_per_dispatch.campaign`` reads the fused engine's copy
+    counter over its dispatches; nothing where the trace found no device
+    plane or the program has no copy counter (the parent's)."""
+    from bench import harness
+
+    mod = harness.load_module(harness.BENCH / "layers"
+                              / "fetch_copies_per_dispatch.campaign.py")
+    monkeypatch.setattr(fused, "_DISPATCHES", [4])
+    monkeypatch.setattr(fused, "_TRANSFER",
+                        {"to_device": 0, "to_host": 0, "copies": 44})
+    traced = {"trace": {"busy_s": 1.0, "window_s": 2.0}}
+    assert mod.read(traced) == 11.0
+    assert mod.read({"trace": None}) is None
+    monkeypatch.setattr(fused, "_DISPATCHES", [0])
+    assert mod.read(traced) is None
+    monkeypatch.delattr(fused, "fetch_copies")
+    assert mod.read(traced) is None

@@ -125,10 +125,9 @@ def test_sharded_loop_compiles_on_2x2_mesh(topo):
     mesh = Mesh(np.array(topo.devices), ("i",))
     _init, loop = fused._build_loop(n, p, k, min(n - 1, p - 1), S_local,
                                     fused.TPU_BAND)
-    wrapped = sharded._shard_wrap(loop, n_state_out=6, n_rec_out=4,
-                                  mesh=mesh)
+    wrapped = sharded._shard_wrap(loop, fused.TPU_BAND, mesh=mesh)
     args = _shapes(lambda s: NamedSharding(mesh, P() if not s else P("i")),
                    *_loop_args(n, p, S_local * len(topo.devices)))
     compiled = jax.jit(wrapped).lower(*args).compile()
-    out = compiled.output_shardings
-    assert len(out[0].device_set) == len(topo.devices) == 4
+    out = compiled.output_shardings   # the certified loop's one record
+    assert len(out.device_set) == len(topo.devices) == 4
