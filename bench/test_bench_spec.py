@@ -17,6 +17,10 @@ SPEC = harness.load_spec()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 WORKLOADS = [w["name"] for w in SPEC["workloads"]]
+# what a traffic file of each kind of cell must give
+TRAFFIC = {
+    "campaign": lambda t: t["families"] and t["pool_campaigns"] > 0,
+}
 
 
 def test_top_level_keys_and_paths():
@@ -34,7 +38,7 @@ def test_workload_resolves_to_its_files(name):
     assert cell.config["name"] == cell.workload["config"]
     assert (ROOT / "bench" / "reference" / f"{cell.config['kind']}.py"
             ).is_file()
-    assert cell.traffic["families"] and cell.traffic["pool_campaigns"] > 0
+    assert TRAFFIC[cell.config["kind"]](cell.traffic)
     e2e = cell.readers(trace=False)
     layers = cell.readers(trace=True)
     assert "setup_s" in e2e and len(e2e) >= 2
@@ -70,12 +74,47 @@ def test_names_units_and_bounds():
         assert w["chips"] in (1, 4)
 
 
-def test_configuration_files_state_their_cut():
-    for c in SPEC["configs"]:
+def _assert_cuts_stated(spec):
+    """Each configuration file names itself, states the cut that
+    ``BENCHMARK.json`` lists, its source and what it assumed, and the chips
+    of every cell that runs it."""
+    for c in spec["configs"]:
         cfg = json.loads((ROOT / c["file"]).read_text())
         assert cfg["name"] == c["name"]
-        assert cfg["reduced"] == c["reduced"] == []
-        assert cfg["source"] and cfg["assumed"] and cfg["chips"] == 1
+        assert cfg["reduced"] == c["reduced"]
+        assert cfg["source"] and cfg["assumed"]
+        assert {w["chips"] for w in spec["workloads"]
+                if w["config"] == c["name"]} == {cfg["chips"]}
+
+
+def test_configuration_files_state_their_cut():
+    _assert_cuts_stated(SPEC)
+
+
+def test_a_four_chip_configuration_resolves(tmp_path):
+    base = json.loads(
+        (ROOT / "bench" / "configs" / "paper_e1e4_n40_p100.json").read_text())
+    cfg = dict(base, name="four_chip_probe", chips=4, n=160, p=1000,
+               n_bounds=8, reduced=["n_bounds"])
+    (tmp_path / "four_chip_probe.json").write_text(json.dumps(cfg))
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append({"name": "four_chip_probe",
+                            "source": "https://arxiv.org/abs/0706.4009",
+                            "file": str(tmp_path / "four_chip_probe.json"),
+                            "reduced": ["n_bounds"], "why": "four chips"})
+    spec["workloads"].append({"name": "campaign_probe4",
+                              "config": "four_chip_probe",
+                              "traffic": "paper_mixed", "chips": 4,
+                              "why": "a configuration cut for four chips"})
+    _assert_cuts_stated(spec)
+    cell = harness.Cell("campaign_probe4", spec)
+    assert cell.chips == cell.config["chips"] == 4
+    assert TRAFFIC[cell.config["kind"]](cell.traffic)
+    assert "setup_s" in cell.readers(trace=False)
+    assert hasattr(cell.kind(), "check")
+    spec["workloads"][-1]["chips"] = 1
+    with pytest.raises(AssertionError):
+        _assert_cuts_stated(spec)
 
 
 def _run(args, cwd, env_extra=None):
