@@ -5,8 +5,9 @@ Everything that belongs to one configuration, traffic mix or metric is found
 by name:
 
 - a cell's configuration is the file that ``BENCHMARK.json`` names for it;
-  its ``kind`` names the module ``bench/kinds/<kind>.py`` (set-up, window,
-  correctness check) and its plain reference under ``bench/reference/``;
+  its ``kind`` names the module ``bench/kinds/<kind>.py`` (what its traffic
+  files must give, set-up, window, correctness check) and its plain
+  reference ``bench/reference/<kind>.py``;
 - its traffic mix is ``bench/traffic/<traffic>.json``, parameters for the
   general generators of ``bench/traffic/generators.py``;
 - an end-to-end metric is read by ``bench/metrics/<name>.py`` and a

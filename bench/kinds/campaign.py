@@ -28,6 +28,13 @@ from bench.reference import campaign as reference
 from bench.traffic import generators
 
 
+def traffic_ok(traffic: dict) -> bool:
+    """Whether a traffic file gives what a campaign cell deals: at least one
+    family and a pool of at least one campaign."""
+    return (bool(traffic.get("families"))
+            and traffic.get("pool_campaigns", 0) > 0)
+
+
 def _instances(cfg, families, pairs, seed0):
     return {f: [generators.gen_instance(cfg["families"][f], cfg["n"],
                                         cfg["p"], cfg["speeds"], seed0 + k)

@@ -2,10 +2,12 @@
 parameters of a traffic file and the run's seed.
 
 Copied from ``src/repro/sim/generators.py`` (``gen_instance``,
-``uniform_comp``, ``uniform_comm``, ``constant_comm``), so that a later change
-of the program's generators cannot move the yardstick; the families are given
-as data in the configuration file.  Instances are plain arrays ``(w, delta,
-s)``.  Nothing here imports the program.
+``uniform_comp``, ``uniform_comm``, ``constant_comm``, and the draw of
+``jpeg_profile_comp``/``jpeg_profile_comm``), so that a later change of the
+program's generators cannot move the yardstick; the families are given as data
+in the configuration file.  A fixed chain, such as a model's layers, is a
+``profile``: its per-stage values written out in full.  Instances are plain
+arrays ``(w, delta, s)``.  Nothing here imports the program.
 """
 
 from __future__ import annotations
@@ -14,6 +16,12 @@ import numpy as np
 
 
 def _sampler(spec, rng, size):
+    """``size`` values of a sampler given as data: ``["uniform_int", lo,
+    hi]``, ``["uniform", lo, hi]``, ``["constant", v]``, or ``["profile",
+    values]`` and ``["profile", values, jitter]``.  A profile is taken as it
+    stands and draws nothing; with ``jitter`` each value is scaled by one
+    draw of ``rng.uniform(1 - jitter, 1 + jitter, size)``.  A profile of
+    another length than ``size`` raises: it is neither tiled nor padded."""
     kind, *args = spec
     if kind == "uniform_int":
         lo, hi = args
@@ -23,6 +31,16 @@ def _sampler(spec, rng, size):
         return rng.uniform(lo, hi, size)
     if kind == "constant":
         return np.full(size, float(args[0]))
+    if kind == "profile":
+        values, *jitter = args
+        base = np.asarray(values, dtype=float)
+        if base.shape != (size,):
+            raise ValueError(f"profile of {base.size} values where {size} "
+                             "are drawn")
+        if not jitter:
+            return base
+        (j,) = jitter
+        return base * rng.uniform(1.0 - j, 1.0 + j, size)
     raise ValueError(f"unknown sampler {kind!r}")
 
 
