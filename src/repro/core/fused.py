@@ -58,7 +58,9 @@ by more than the error band, parks the rows whose step is not, and the host
 replays the device's decisions and makes the parked ones in float64 with the
 numpy engine's own code (:func:`run_loop`) — same trajectories and floats,
 at the price of one more dispatch round per parked step.  The certified
-program returns only those decisions, one packed int32 record a dispatch.
+program takes its inputs as two packed blocks, one float64 and one int64
+(:func:`_pack_inputs`), so each dispatch makes two uploads, and returns only
+those decisions, one packed int32 record a dispatch.
 The H4 bisection then probes through this loop from the host
 (``batched.batched_sp_bi_p``).
 
@@ -86,7 +88,7 @@ __all__ = ["fused_available", "run_fused", "run_fused_bisection",
            "trace_count", "reset_trace_count", "traced_shapes",
            "decision_counts", "device_band", "run_loop",
            "dispatch_count", "reset_dispatch_count", "transfer_bytes",
-           "fetch_copies",
+           "fetch_copies", "launch_copies",
            "bucket_trace_count", "reset_bucket_trace_count",
            "bucket_sizes", "bucket_index", "trace_budget",
            "enable_persistent_cache"]
@@ -109,9 +111,9 @@ _DISPATCHES = [0]
 # the device decided, and parked row-steps the host re-decided in float64
 _DECISIONS = {"device": 0, "host": 0}
 # bytes handed to this engine's jitted programs (every argument of every
-# dispatch) and copied back (every output), and the device-to-host copies,
-# since the last dispatch-count reset
-_TRANSFER = {"to_device": 0, "to_host": 0, "copies": 0}
+# dispatch) and copied back (every output), the host arrays uploaded and the
+# device-to-host copies, since the last dispatch-count reset
+_TRANSFER = {"to_device": 0, "to_host": 0, "uploads": 0, "copies": 0}
 
 # Relative error bound, against each row's magnitude scale, that certified
 # loops allow the device arithmetic.  TPU float64 is emulated with float32
@@ -188,7 +190,7 @@ def dispatch_count() -> int:
 def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
     _DECISIONS.update(device=0, host=0)
-    _TRANSFER.update(to_device=0, to_host=0, copies=0)
+    _TRANSFER.update(to_device=0, to_host=0, uploads=0, copies=0)
 
 
 def transfer_bytes() -> dict:
@@ -207,6 +209,14 @@ def fetch_copies() -> int:
     programs' every output); the sharded engine counts its own
     (``sharded.fetch_copies``)."""
     return _TRANSFER["copies"]
+
+
+def launch_copies() -> int:
+    """Host arrays uploaded since :func:`reset_dispatch_count`, one per
+    argument of every call: two for the certified loop's packed blocks, 17
+    for the uncertified loop, ten for the bisection; the sharded engine
+    counts its own (``sharded.launch_copies``)."""
+    return _TRANSFER["uploads"]
 
 
 def decision_counts() -> dict:
@@ -783,16 +793,94 @@ def _build_loop(n: int, p: int, k: int, T: int, S: int,
     return init_state, loop
 
 
+def _loop_params(n: int, p: int) -> tuple:
+    """The arguments of :func:`_build_loop`'s loop in order: name, dtype and
+    the shape of one row's value (None for the two scalars, ``b`` and
+    ``zero``)."""
+    f, i, b = np.float64, np.int64, np.bool_
+    return (("delta", f, (n + 1,)), ("s", f, (p,)), ("b", f, None),
+            ("zero", f, None), ("prefix", f, (n + 1,)), ("order", i, (p,)),
+            ("bi_mode", b, ()), ("stop", f, ()), ("lat_limit", f, ()),
+            ("active", b, ()), ("arr", f, (n, 5)), ("m", i, ()),
+            ("next_idx", i, ()), ("lat_sum", f, ()), ("splits", i, ()),
+            ("scale", f, ()), ("sbits", i, (p,)))
+
+
+@functools.lru_cache(maxsize=None)
+def _input_columns(n: int, p: int) -> tuple:
+    """Where each loop argument lies in the two packed input blocks:
+    ``({name: (block, column slice)}, (float columns, int columns))``.
+    Block 0 is float64 and holds the float arguments, block 1 int64 the
+    others, bools as 0/1; a scalar fills one column, repeated down it."""
+    cols, ends = {}, [0, 0]
+    for name, dtype, shape in _loop_params(n, p):
+        blk = 0 if dtype is np.float64 else 1
+        width = int(np.prod(shape or ()))
+        cols[name] = (blk, slice(ends[blk], ends[blk] + width))
+        ends[blk] += width
+    return cols, tuple(ends)
+
+
+def _fill_inputs(blocks: tuple, n: int, p: int, **values) -> None:
+    """Write ``values`` (loop arguments by name, row-leading) into their
+    columns of the host ``blocks``."""
+    cols, _widths = _input_columns(n, p)
+    for name, value in values.items():
+        blk, sl = cols[name]
+        rows = blocks[blk].shape[0]
+        blocks[blk][:, sl] = (np.reshape(value, (rows, -1))
+                              if np.ndim(value) else value)
+
+
+def _pack_inputs(n: int, p: int, *args) -> tuple:
+    """The loop's 17 arguments (:func:`_loop_params`) as two host blocks,
+    float64 (S, F) and int64 (S, I): the certified program's inputs."""
+    _cols, (F, I) = _input_columns(n, p)
+    S = np.shape(args[0])[0]
+    blocks = (np.empty((S, F)), np.empty((S, I), dtype=np.int64))
+    _fill_inputs(blocks, n, p,
+                 **{name: a for (name, _, _), a in zip(_loop_params(n, p),
+                                                       args)})
+    return blocks
+
+
+def _unpack_inputs(n: int, p: int, fblock, iblock) -> tuple:
+    """The loop's 17 arguments from the two packed blocks, by static
+    slices, on the device or the host: floats and ints as they lie, bools
+    as ``!= 0``, each scalar as the first element of its column."""
+    cols, _widths = _input_columns(n, p)
+    args = []
+    for name, dtype, shape in _loop_params(n, p):
+        blk, sl = cols[name]
+        x = (fblock, iblock)[blk][:, sl]
+        x = x[0, 0] if shape is None else x.reshape((x.shape[0],) + shape)
+        args.append(x != 0 if dtype is np.bool_ else x)
+    return tuple(args)
+
+
+def _packed_inputs(loop: Callable, n: int, p: int) -> Callable:
+    """``loop`` taking its arguments as the two blocks of
+    :func:`_pack_inputs`: one upload each in place of 17.  The uncertified
+    loop keeps its separate arguments, whose state buffers it donates."""
+    def packed(fblock, iblock):
+        return loop(*_unpack_inputs(n, p, fblock, iblock))
+
+    return packed
+
+
 @functools.lru_cache(maxsize=None)
 def _get_loop(n: int, p: int, k: int, T: int, S: int,
               band: float = 0.0) -> Callable:
     """The jitted fused loop for static shape (n, p, k), cached per shape.
     Where it returns the state (``band == 0``), the five carried SoA state
     buffers (arr, m, next_idx, lat_sum, splits) are donated: XLA reuses
-    their device buffers for the outputs."""
+    their device buffers for the outputs.  The certified loop (``band >
+    0``) takes the two packed blocks of :func:`_pack_inputs`."""
     import jax
 
     _init_state, loop = _build_loop(n, p, k, T, S, band)
+    if band:
+        loop = _packed_inputs(loop, n, p)
     return jax.jit(_named(f"fused_loop_k{k}", n, p, loop),
                    donate_argnums=_donated(band))
 
@@ -919,18 +1007,20 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
     """Host driver shared by the fused and sharded engines: run the traced
     loop ``get_program(T)`` over ``state``'s active rows in chunks of ``S``,
     counting each dispatch in ``dispatches[0]``, and the bytes of its
-    arguments and outputs and its copies back in ``transfer`` (the engine's
-    own counters).
+    arguments and outputs, its uploads and its copies back in ``transfer``
+    (the engine's own counters).
 
-    ``band == 0``: one dispatch per chunk; the device's final state and
-    per-iteration records are written back as they are.  ``band > 0``
-    (certified loop): the program returns its decisions alone, one packed
-    int32 record (:data:`REC_FIELDS`) fetched in one copy; they are replayed
-    on the host in float64 with the numpy engine's own ``_apply_splits``, so
-    every float is the numpy engine's; rows the device parked take one
-    float64 step of the numpy loop and go back to the device, until no row
-    is active.  Either way ``record`` sees the numpy engine's lockstep
-    sequence.
+    ``band == 0``: one dispatch per chunk, 17 arguments; the device's final
+    state and per-iteration records are written back as they are.  ``band >
+    0`` (certified loop): the program takes a chunk's inputs as two packed
+    blocks (:func:`_pack_inputs`), its rows gathered from blocks packed once
+    a call with their current state written in, and returns its decisions
+    alone, one packed int32 record (:data:`REC_FIELDS`) fetched in one copy;
+    they are replayed on the host in float64 with the numpy engine's own
+    ``_apply_splits``, so every float is the numpy engine's; rows the device
+    parked take one float64 step of the numpy loop and go back to the
+    device, until no row is active.  Either way ``record`` sees the numpy
+    engine's lockstep sequence.
 
     Each dispatch runs under the spans ``fused.launch`` and ``fused.fetch``
     (with ``fused.wait`` split off while a profiler records), the host's
@@ -959,6 +1049,13 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
         scale = ((pb.prefix[:, n] - pb.prefix[:, 0]) / pb.s.min(axis=1)
                  + (n + 2) * pb.delta.max(axis=1) / b)
         sbits = np.ascontiguousarray(pb.s).view(np.int64)
+        # every row's inputs packed once a call; a chunk gathers its rows
+        # and writes in their current state
+        static = _pack_inputs(n, p, pb.delta, pb.s, b, 0.0, pb.prefix,
+                              pb.order, bi_mode, stop, lat_limit,
+                              state.active, state.arr, state.m,
+                              state.next_idx, state.lat_sum, state.splits,
+                              scale, sbits)
     base = state.splits.copy()
     steps = []       # (rows, split index per row, period, latency)
 
@@ -976,15 +1073,24 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
                 act = np.zeros(S, dtype=bool)
                 act[:r] = True
                 dispatches[0] += 1
-                # the SoA state slices are fresh fancy-index copies, safe to
-                # donate (the uncertified program does)
-                out = _call(fn, transfer, pb.delta[sel], pb.s[sel], b,
-                            np.float64(0.0), pb.prefix[sel],
-                            pb.order[sel].astype(np.int64), bi_mode[sel],
-                            stop[sel], lat_limit[sel], act, state.arr[sel],
-                            state.m[sel], state.next_idx[sel],
-                            state.lat_sum[sel], state.splits[sel],
-                            scale[sel], sbits[sel])
+                if band:
+                    blocks = (static[0][sel], static[1][sel])
+                    _fill_inputs(blocks, n, p, active=act,
+                                 arr=state.arr[sel], m=state.m[sel],
+                                 next_idx=state.next_idx[sel],
+                                 lat_sum=state.lat_sum[sel],
+                                 splits=state.splits[sel])
+                    out = _call(fn, transfer, *blocks)
+                else:
+                    # the SoA state slices are fresh fancy-index copies,
+                    # safe to donate
+                    out = _call(fn, transfer, pb.delta[sel], pb.s[sel], b,
+                                np.float64(0.0), pb.prefix[sel],
+                                pb.order[sel].astype(np.int64), bi_mode[sel],
+                                stop[sel], lat_limit[sel], act,
+                                state.arr[sel], state.m[sel],
+                                state.next_idx[sel], state.lat_sum[sel],
+                                state.splits[sel], scale[sel], sbits[sel])
             if not band:
                 (arr, m, next_idx, lat_sum, splits, _park, per_rec, lat_rec,
                  acc_rec, _dec, t_used) = _fetch(out, transfer)
@@ -1047,9 +1153,11 @@ def run_loop(state, k: int, bi_mode, stop, lat_limit, record, S: int,
 
 def _call(fn: Callable, transfer: dict, *args):
     """``fn(*args)``, the ``nbytes`` of its arguments counted into
-    ``transfer["to_device"]``; the host arrays live only for the call, as
+    ``transfer["to_device"]`` and their number, one upload each, into
+    ``transfer["uploads"]``; the host arrays live only for the call, as
     when they are passed to ``fn`` directly."""
     transfer["to_device"] += sum(a.nbytes for a in args)
+    transfer["uploads"] += len(args)
     return fn(*args)
 
 

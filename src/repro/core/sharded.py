@@ -57,16 +57,16 @@ from .spans import span
 __all__ = ["sharded_available", "device_count", "run_sharded",
            "run_sharded_bisection", "trace_count", "reset_trace_count",
            "dispatch_count", "reset_dispatch_count", "transfer_bytes",
-           "fetch_copies", "output_devices"]
+           "fetch_copies", "launch_copies", "output_devices"]
 
 # traces / dispatches of the SPMD programs, mirroring fused.py's counters
 # (the shared bucket branches still count into fused._BUCKET_TRACES).
 _TRACES = [0]
 _DISPATCHES = [0]
-# bytes of every argument and output of the SPMD programs' calls and their
-# copies back, as fused.transfer_bytes and fused.fetch_copies count the fused
-# engine's
-_TRANSFER = {"to_device": 0, "to_host": 0, "copies": 0}
+# bytes of every argument and output of the SPMD programs' calls, their
+# uploads and their copies back, as fused.transfer_bytes,
+# fused.launch_copies and fused.fetch_copies count the fused engine's
+_TRANSFER = {"to_device": 0, "to_host": 0, "uploads": 0, "copies": 0}
 # devices the outputs of the latest dispatch are laid out over
 _OUT_DEVICES = [0]
 
@@ -89,7 +89,7 @@ def dispatch_count() -> int:
 
 def reset_dispatch_count() -> None:
     _DISPATCHES[0] = 0
-    _TRANSFER.update(to_device=0, to_host=0, copies=0)
+    _TRANSFER.update(to_device=0, to_host=0, uploads=0, copies=0)
 
 
 def transfer_bytes() -> dict:
@@ -104,6 +104,13 @@ def fetch_copies() -> int:
     :func:`reset_dispatch_count`, as :func:`fused.fetch_copies` counts the
     fused engine's."""
     return _TRANSFER["copies"]
+
+
+def launch_copies() -> int:
+    """Host arrays uploaded to the SPMD programs since
+    :func:`reset_dispatch_count`, as :func:`fused.launch_copies` counts the
+    fused engine's."""
+    return _TRANSFER["uploads"]
 
 
 def output_devices() -> int:
@@ -138,7 +145,8 @@ def _mesh():
 
 def _shard_wrap(fn: Callable, band: float, mesh) -> Callable:
     """Wrap ``fused._build_loop``'s unjitted loop (built at ``band``) in
-    ``shard_map`` over the row axis.
+    ``shard_map`` over the row axis; certified, the loop takes the two
+    packed blocks of ``fused._pack_inputs``, sharded along rows as well.
 
     Uncertified (``band == 0``) it returns ``(*state..., *records..., t)``:
     six row-leading state outputs, four (T, S_local, ...) records and a
@@ -180,11 +188,13 @@ def _get_sharded_loop(n: int, p: int, k: int, T: int, S_local: int,
                       band: float = 0.0) -> Callable:
     """The jitted SPMD fused loop for static shape (n, p, k): per-shard rows
     ``S_local``, global rows ``S_local * device_count()``.  SoA state buffers
-    donated where the loop returns the state, exactly like
-    ``fused._get_loop``."""
+    donated where the loop returns the state, and the two packed input
+    blocks taken where it is certified, exactly like ``fused._get_loop``."""
     import jax
 
     _init_state, loop = fused._build_loop(n, p, k, T, S_local, band)
+    if band:
+        loop = fused._packed_inputs(loop, n, p)
     jitted = jax.jit(_shard_wrap(loop, band, _mesh()),
                      donate_argnums=fused._donated(band))
 

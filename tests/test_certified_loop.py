@@ -14,7 +14,7 @@ host decides nearly every step.  The ``band`` fixture is in
 import numpy as np
 import pytest
 
-from repro.core import fused, sharded
+from repro.core import batched, fused, sharded
 from repro.core.batched import (batched_min_period, batched_sp_bi_p,
                                 batched_trajectories)
 from repro.core.metrics import single_processor_mapping
@@ -71,7 +71,9 @@ def test_certified_campaign_matches_numpy_and_parks_ties(band):
 
 def _perturbed_loops(monkeypatch, eps: float) -> None:
     """Make every device dispatch see its float inputs and carried state
-    off by up to ``eps`` relative: an inexact device, simulated on the CPU."""
+    off by up to ``eps`` relative: an inexact device, simulated on the CPU.
+    The certified program takes its inputs as two packed blocks, which are
+    unpacked on the host, perturbed and packed again."""
     rng = np.random.default_rng(0)
     get = fused._get_loop
 
@@ -82,13 +84,15 @@ def _perturbed_loops(monkeypatch, eps: float) -> None:
     def perturbed(n, p, k, T, S, band=0.0):
         fn = get(n, p, k, T, S, band)
 
-        def run(delta, s, b, zero, prefix, order, bi, stop, lim, act, arr,
-                m, nx, lat, sp, scale, sbits):
+        def run(*blocks):
+            (delta, s, b, zero, prefix, order, bi, stop, lim, act, arr,
+             m, nx, lat, sp, scale, sbits) = fused._unpack_inputs(n, p,
+                                                                  *blocks)
             arr = arr.copy()
             arr[:, :, 3:] = noisy(arr[:, :, 3:])
-            return fn(noisy(delta), noisy(s), b, zero, noisy(prefix), order,
-                      bi, stop, lim, act, arr, m, nx, noisy(lat), sp, scale,
-                      sbits)
+            return fn(*fused._pack_inputs(
+                n, p, noisy(delta), noisy(s), b, zero, noisy(prefix), order,
+                bi, stop, lim, act, arr, m, nx, noisy(lat), sp, scale, sbits))
         return run
 
     monkeypatch.setattr(fused, "_get_loop", perturbed)
@@ -143,8 +147,9 @@ def test_certified_program_returns_one_packed_int32_record(band, monkeypatch,
                                                            engine, k):
     """Each certified call returns exactly one int32 array of (T + 1) x 13 x
     S: the decisions the device accepted, each as the uncertified program
-    makes it from the same arguments, then each row's parked flag and the
-    iteration count."""
+    makes it from the same arguments (the certified call's two packed
+    blocks, unpacked), then each row's parked flag and the iteration
+    count."""
     import jax
 
     mod, name = ((fused, "_get_loop") if engine == "fused"
@@ -176,10 +181,79 @@ def test_certified_program_returns_one_packed_int32_record(band, monkeypatch,
         park, t = rec[T, 0], rec[T, 1]
         assert set(np.unique(park)) <= {0, 1} and (rec[T, 2:] == 0).all()
         assert (t == t.max()).all() if engine == "fused" else t.max() <= T
-        out = get(n_, p_, k_, T, S_local, 0.0)(*args)
+        assert len(args) == 2
+        out = get(n_, p_, k_, T, S_local, 0.0)(
+            *fused._unpack_inputs(n_, p_, *args))
         acc_rec, dec_rec = np.asarray(out[8]), np.asarray(out[9])
         acc = rec[:T, -1].astype(bool)                       # (T, S)
         assert not acc[t.max():].any()
         assert (acc <= acc_rec).all()
         assert np.array_equal(rec[:T, :-1].transpose(0, 2, 1)[acc],
                               dec_rec[acc])
+
+
+@pytest.mark.parametrize("engine", ["fused", "sharded"])
+@pytest.mark.parametrize("k", [1, 2])
+def test_packed_launch_equals_per_array_launch(band, monkeypatch, engine, k):
+    """The two packed blocks that ``run_loop`` uploads for a chunk hold the
+    chunk's 17 arrays exactly, padded rows (``active`` false) included, and
+    the certified program given them returns, bit for bit, the record of
+    ``_build_loop``'s loop called with those 17 arrays (through the same
+    ``shard_map`` for the sharded engine).  The rows start two float64
+    steps in, with modes, stops and latency limits that differ by row."""
+    import jax
+
+    mod, name = ((fused, "_get_loop") if engine == "fused"
+                 else (sharded, "_get_sharded_loop"))
+    get = getattr(mod, name)
+    calls = []
+
+    def spy(*shape):
+        fn = get(*shape)
+
+        def run(*args):
+            calls.append((shape, args))
+            return fn(*args)
+        return run
+
+    monkeypatch.setattr(mod, name, spy)
+    n, p, B = 12, 10, 6
+    pb = batched._as_problem_batch(gen_instance_batch("E3", n, p, SEEDS))
+    state = batched._BatchState(pb)
+    bi_mode = np.arange(B) % 2 == 1
+    stop = np.full(B, -np.inf)
+    stop[1] = 0.5 * state.period()[1]
+    lat_limit = np.full(B, np.inf)
+    lat_limit[2] = 2.0 * (state.lat_sum + state.tail)[2]
+    batched._numpy_loop(state, np.arange(B), k, bi_mode, stop, lat_limit,
+                        max_iters=2)
+    rows = np.nonzero(state.active)[0]
+    assert rows.size and state.splits.max() >= 2
+    before = [x.copy() for x in (state.arr, state.m, state.next_idx,
+                                 state.lat_sum, state.splits)]
+    run = fused.run_fused if engine == "fused" else sharded.run_sharded
+    run(state, k, bi_mode, stop, lat_limit)
+    shape, blocks = calls[0]
+    _n, _p, _k, T, S_local, _band = shape
+    S = blocks[0].shape[0]
+    assert len(blocks) == 2 and S > rows.size
+    sel = np.concatenate([rows, np.repeat(rows[:1], S - rows.size)])
+    scale = ((pb.prefix[:, n] - pb.prefix[:, 0]) / pb.s.min(axis=1)
+             + (n + 2) * pb.delta.max(axis=1) / pb.b)
+    arr, m, nx, lat, sp = (x[sel] for x in before)
+    args = (pb.delta[sel], pb.s[sel], np.float64(pb.b), np.float64(0.0),
+            pb.prefix[sel], pb.order[sel].astype(np.int64), bi_mode[sel],
+            stop[sel], lat_limit[sel], np.arange(S) < rows.size, arr, m, nx,
+            lat, sp, scale[sel], pb.s[sel].view(np.int64))
+    for got, want in zip(fused._unpack_inputs(n, p, *blocks), args,
+                         strict=True):
+        assert np.asarray(got).dtype == np.asarray(want).dtype
+        assert np.array_equal(got, want)
+    loop = fused._build_loop(n, p, k, T, S_local, fused.TPU_BAND)[1]
+    if engine == "sharded":
+        loop = sharded._shard_wrap(loop, fused.TPU_BAND, sharded._mesh())
+    want = np.asarray(jax.jit(loop)(*args))
+    got = np.asarray(get(*shape)(*blocks))
+    assert got.dtype == want.dtype == np.int32
+    assert np.array_equal(got, want)
+    assert want[:T, -1].any()

@@ -75,7 +75,9 @@ def _loop_bytes(n, p, S, T, t_rows=1, certified=False):
     """Argument and output bytes of one ``fused_loop`` call, from the
     program's contract (``fused._build_loop``): float64 and int64 are 8
     bytes, int32 4, bool 1.  The sharded program returns ``t`` per row
-    (``t_rows=S``).  The certified program returns one int32 record of
+    (``t_rows=S``).  The certified program takes the same arguments as two
+    blocks of S rows, float64 and int64 (bools as 0/1, each scalar a
+    column: ``fused._pack_inputs``), and returns one int32 record of
     (T + 1) x 13 x S."""
     f8, i4, b1 = 8, 4, 1
     args = (2 * S * (n + 1) * f8          # delta, prefix
@@ -90,6 +92,13 @@ def _loop_bytes(n, p, S, T, t_rows=1, certified=False):
             + 2 * T * S * f8 + T * S * b1  # per_rec, lat_rec, acc_rec
             + T * S * 12 * f8 + t_rows * f8)  # dec_rec, t
     if certified:
+        floats = (2 * (n + 1) + p + 5 * n   # delta, prefix; s; arr
+                  + 6)                      # b, zero, stop, lat_limit,
+        #                                     lat_sum, scale
+        ints = (2 * p                       # order, sbits
+                + 5)                        # bi_mode, active, m, next_idx,
+        #                                     splits
+        args = S * floats * f8 + S * ints * f8
         outs = (T + 1) * len(fused.REC_FIELDS) * S * i4
     return args, outs
 
@@ -110,9 +119,10 @@ def _bisect_bytes(n, p, S):
     ids=["H1", "H3", "bisection", "H1-certified", "H3-certified"])
 def test_transfer_bytes_match_the_shapes(band, engine, code, certified):
     """Each engine counts its own calls, beside its own dispatch counter:
-    eleven copies a call of the uncertified loop and of the bisection, one
-    of the certified loop's packed record (which calls again for the rows
-    it parks)."""
+    17 uploads a call of the uncertified loop, ten of the bisection and two
+    of the certified loop's packed blocks; eleven copies back a call of the
+    uncertified loop and of the bisection, one of the certified loop's
+    packed record (which calls again for the rows it parks)."""
     band(fused.TPU_BAND if certified else 0.0)
     n, p, B = 8, 6, 5
     batch = gen_instance_batch("E2", n, p, range(B))
@@ -140,12 +150,14 @@ def test_transfer_bytes_match_the_shapes(band, engine, code, certified):
     assert (got["to_device"], got["to_host"]) == (calls * want[0],
                                                   calls * want[1])
     assert mine.fetch_copies() == calls * (1 if certified else 11)
+    uploads = 2 if certified else 10 if code == "bisection" else 17
+    assert mine.launch_copies() == calls * uploads
     assert other.dispatch_count() == 0
     assert other.transfer_bytes() == {"to_device": 0, "to_host": 0}
-    assert other.fetch_copies() == 0
+    assert other.fetch_copies() == other.launch_copies() == 0
     mine.reset_dispatch_count()
     assert mine.transfer_bytes() == {"to_device": 0, "to_host": 0}
-    assert mine.fetch_copies() == 0
+    assert mine.fetch_copies() == mine.launch_copies() == 0
 
 
 def test_no_explicit_wait_without_a_profiler(monkeypatch):
@@ -182,4 +194,25 @@ def test_copies_reader(monkeypatch):
     monkeypatch.setattr(fused, "_DISPATCHES", [0])
     assert mod.read(traced) is None
     monkeypatch.delattr(fused, "fetch_copies")
+    assert mod.read(traced) is None
+
+
+def test_launch_copies_reader(monkeypatch):
+    """``launch_copies_per_dispatch.campaign`` reads the fused engine's
+    upload counter over its dispatches: 2.0 for the certified loop's packed
+    blocks; nothing where the trace found no device plane or the program
+    has no upload counter (the parent's)."""
+    from bench import harness
+
+    mod = harness.load_module(harness.BENCH / "layers"
+                              / "launch_copies_per_dispatch.campaign.py")
+    monkeypatch.setattr(fused, "_DISPATCHES", [4])
+    monkeypatch.setattr(fused, "_TRANSFER", {"to_device": 0, "to_host": 0,
+                                             "uploads": 8, "copies": 4})
+    traced = {"trace": {"busy_s": 1.0, "window_s": 2.0}}
+    assert mod.read(traced) == 2.0
+    assert mod.read({"trace": None}) is None
+    monkeypatch.setattr(fused, "_DISPATCHES", [0])
+    assert mod.read(traced) is None
+    monkeypatch.delattr(fused, "launch_copies")
     assert mod.read(traced) is None

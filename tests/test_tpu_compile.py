@@ -131,3 +131,34 @@ def test_sharded_loop_compiles_on_2x2_mesh(topo):
     compiled = jax.jit(wrapped).lower(*args).compile()
     out = compiled.output_shardings   # the certified loop's one record
     assert len(out.device_set) == len(topo.devices) == 4
+
+
+def _packed_args(n, p, S):
+    """Argument specs of the certified program's two packed input blocks
+    (``fused._pack_inputs``) for S rows."""
+    _cols, (F, I) = fused._input_columns(n, p)
+    return [((S, F), jnp.float64), ((S, I), jnp.int64)]
+
+
+@pytest.mark.parametrize("n,k", [(6, 1), (5, 2)])
+def test_packed_certified_loop_compiles(one_chip, n, k):
+    p, S = 4, 8
+    _init, loop = fused._build_loop(n, p, k, min(n - 1, p - 1), S,
+                                    fused.TPU_BAND)
+    args = _shapes(lambda s: one_chip, *_packed_args(n, p, S))
+    compiled = jax.jit(fused._packed_inputs(loop, n, p)).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_packed_sharded_loop_compiles_on_2x2_mesh(topo):
+    n, p, k, S_local = 5, 4, 2, 8
+    mesh = Mesh(np.array(topo.devices), ("i",))
+    _init, loop = fused._build_loop(n, p, k, min(n - 1, p - 1), S_local,
+                                    fused.TPU_BAND)
+    wrapped = sharded._shard_wrap(fused._packed_inputs(loop, n, p),
+                                  fused.TPU_BAND, mesh=mesh)
+    args = _shapes(lambda s: NamedSharding(mesh, P("i")),
+                   *_packed_args(n, p, S_local * len(topo.devices)))
+    compiled = jax.jit(wrapped).lower(*args).compile()
+    out = compiled.output_shardings   # the certified loop's one record
+    assert len(out.device_set) == len(topo.devices) == 4
